@@ -6,8 +6,8 @@ output size are static under jit — a server sweeping request sizes pays a
 compile per size.  The engine pads every shape knob to power-of-two buckets
 (DESIGN.md §4), so the whole sweep runs one AOT-compiled executable.
 
-Measured here (jnp impl; the Pallas kernel only runs in interpret mode on
-this container, which times Python, not hardware — EXPERIMENTS.md §Perf):
+Measured here (jnp impl; the Pallas kernel's mode follows the platform —
+interpreted on CPU, which times Python, not hardware; EXPERIMENTS.md §Perf):
 
   * cold:  one pass over ``len(SIZES)`` distinct request sizes through
            ``walk_decode_batch`` — each size jit-compiles, as in production

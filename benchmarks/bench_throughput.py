@@ -1,11 +1,11 @@
 """Paper Figure 7 (CPU side): decode throughput of Single-Thread vs
 Conventional vs Recoil at matched split counts.
 
-This container is CPU-only, so the measured numbers are for the XLA:CPU
-lowering of the SAME group-stepped walk the Pallas TPU kernel implements;
-the kernel itself is validated in interpret mode (not timed — interpret mode
-measures Python, not TPUs; see EXPERIMENTS.md §Perf for the kernel's
-roofline-based analysis).  The paper's claims reproduced here:
+On CPU the measured numbers are for the XLA:CPU lowering of the SAME
+group-stepped walk the Pallas TPU kernel implements; the kernel's mode
+follows the platform, and on CPU it runs interpreted (not timed —
+interpret mode measures Python, not TPUs; see EXPERIMENTS.md §Perf for the
+kernel's roofline-based analysis).  The paper's claims reproduced here:
 
   * Recoil decode throughput ~= Conventional at the same parallelism;
   * both scale with split count while Single-Thread does not;

@@ -33,6 +33,8 @@ import os
 import sys
 import time
 
+from repro.launch.cache import use_compile_cache
+
 from . import (bench_combine, bench_compression, bench_encode, bench_engine,
                bench_observability, bench_partition_sweep, bench_pipeline,
                bench_predictive, bench_reliability, bench_roofline,
@@ -85,6 +87,7 @@ def main() -> None:
                     help="small datasets / fewer variants (CI mode)")
     ap.add_argument("--only", default="", choices=["", *SUITES])
     args = ap.parse_args()
+    use_compile_cache()
     os.makedirs("benchmarks/results", exist_ok=True)
     names = [args.only] if args.only else list(SUITES)
     summary = {"_quick": args.quick}

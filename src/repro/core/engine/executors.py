@@ -12,7 +12,8 @@ the executable cache and stats; it never branches on the backend.  Backends:
 
   * ``jnp``     — XLA walk over the full device-resident stream (fast CPU
                   path; also the oracle for the others);
-  * ``pallas``  — the TPU kernel (per-block stream slabs, fused scatter);
+  * ``pallas``  — the TPU kernel (per-block stream slabs, fused scatter;
+                  compiled on TPU, interpreted on CPU);
   * ``sharded`` — multi-device shard_map over the split rows, one bucketed
                   executable per (mesh, bucket); lives in
                   ``repro.parallel.decode_shard`` (imported lazily so the
@@ -225,16 +226,21 @@ class JnpExecutor(Executor):
 
 
 class PallasExecutor(Executor):
-    """TPU kernel: lane-packed tiles, per-block stream slabs, fused scatter
-    (``interpret=True`` on CPU containers)."""
+    """TPU kernel: lane-packed tiles, per-block stream slabs, fused scatter.
+
+    The kernel mode follows the platform (``rans_decode.interpret_mode``):
+    compiled by Mosaic on TPU, interpreted on CPU, refused elsewhere.  On
+    TPU only the symbol layout has a kernel Mosaic compiles, so pointer
+    content raises at plan time there."""
 
     impl = "pallas"
 
     def __init__(self, model: StaticModel, packed_lut: bool, luts: tuple, *,
-                 interpret: bool = True, rows_per_block: int = 8,
-                 layout: str = "auto", policy: BucketPolicy | None = None):
+                 rows_per_block: int = 8, layout: str = "auto",
+                 policy: BucketPolicy | None = None):
+        from repro.kernels.rans_decode.rans_decode import interpret_mode
         super().__init__(model, packed_lut, luts, layout, policy)
-        self.interpret = interpret
+        self.interpret = interpret_mode()
         self.rows_per_block = rows_per_block
         # Lazy host materialization for device-resident (ingested / fused)
         # streams: the slab build reads host words, but the copy is deferred
@@ -278,15 +284,22 @@ class PallasExecutor(Executor):
              n_symbols: int) -> DecodePlan:
         from repro.kernels.rans_decode.ops import (build_slabs, pack_batch,
                                                    pad_to_rows)
+        from repro.kernels.rans_decode.rans_decode import (
+            LANES, POINTER_KERNEL_REFUSAL, check_vmem)
         layout = self.select_layout(ds)
+        if layout == "pointer" and not self.interpret:
+            raise NotImplementedError(POINTER_KERNEL_REFUSAL)
         self._count_layout(layout)
         p = self.model.params
         W = batch.ways
         rpb = self.rows_per_block
+        steps_b = self.policy.work(batch.n_steps)
+        if layout == "symbol":
+            check_vmem(steps_b, rpb, sum(-(-l.shape[0] // LANES) * LANES
+                                         for l in self.luts if l is not None))
         packed, per_split, rows, pack, _ = pack_batch(batch)
         rows = pad_to_rows(packed, per_split, rows, pack,
                            self.policy.work(-(-rows // rpb)) * rpb)
-        steps_b = self.policy.work(batch.n_steps)
         out_b = self.policy.mem(n_symbols)
         statics = dict(n_bits=p.n_bits, ways=W, n_steps=steps_b,
                        rows_per_block=rpb, interpret=self.interpret,
@@ -309,8 +322,7 @@ class PallasExecutor(Executor):
             sym_rel_packed = np.ascontiguousarray(
                 np.repeat(sym_rel.reshape(-1, pack), W, axis=1))
             key = (self.impl, layout, self.policy.tag, self.packed_lut,
-                   p.n_bits, W, rows, steps_b, slab_b, out_b, rpb,
-                   self.interpret)
+                   p.n_bits, W, rows, steps_b, slab_b, out_b, rpb)
             args = (jnp.asarray(slabs), *self.luts,
                     jnp.asarray(packed["k"]), jnp.asarray(packed["y"]),
                     jnp.asarray(packed["x0"]), jnp.asarray(sym_rel_packed),
@@ -331,8 +343,7 @@ class PallasExecutor(Executor):
         lo_rows = np.repeat(slab_lo, rpb).astype(np.int32)
         q0_rel = packed["q0"] - lo_rows[:, None]
         key = (self.impl, layout, self.policy.tag, self.packed_lut,
-               p.n_bits, W, rows, steps_b, slab_b, out_b, rpb,
-               self.interpret)
+               p.n_bits, W, rows, steps_b, slab_b, out_b, rpb)
         args = (jnp.asarray(slabs), *self.luts,
                 jnp.asarray(packed["k"]), jnp.asarray(packed["y"]),
                 jnp.asarray(packed["x0"]), jnp.asarray(q0_rel),
@@ -357,14 +368,13 @@ class PallasExecutor(Executor):
 
 
 def make_executor(impl: str, model: StaticModel, packed_lut: bool,
-                  luts: tuple, *, interpret: bool = True,
-                  rows_per_block: int = 8, mesh=None,
+                  luts: tuple, *, rows_per_block: int = 8, mesh=None,
                   layout: str = "auto",
                   policy: BucketPolicy | None = None) -> Executor:
     if impl == "jnp":
         return JnpExecutor(model, packed_lut, luts, layout, policy)
     if impl == "pallas":
-        return PallasExecutor(model, packed_lut, luts, interpret=interpret,
+        return PallasExecutor(model, packed_lut, luts,
                               rows_per_block=rows_per_block, layout=layout,
                               policy=policy)
     if impl == "sharded":

@@ -53,7 +53,8 @@ class DecoderSession:
     """Device-resident Recoil decoder with a bucketed executable cache.
 
     ``impl`` is ``"jnp"`` (XLA walk — the fast CPU path), ``"pallas"`` (the
-    TPU kernel; ``interpret=True`` on CPU containers), or ``"sharded"``
+    TPU kernel; compiled on TPU and interpreted on CPU, as the platform
+    decides), or ``"sharded"``
     (multi-device shard_map over split rows; pass ``mesh=`` or the executor
     builds a 1-D mesh over every visible device).  ``packed_lut`` defaults
     to auto: the §4.4 packed table whenever the model fits it.
@@ -75,9 +76,9 @@ class DecoderSession:
     """
 
     def __init__(self, model: StaticModel, *, impl: str = "jnp",
-                 packed_lut: bool | None = None, interpret: bool = True,
-                 rows_per_block: int = 8, mesh=None, layout: str = "auto",
-                 policy=None, profiler=None):
+                 packed_lut: bool | None = None, rows_per_block: int = 8,
+                 mesh=None, layout: str = "auto", policy=None,
+                 profiler=None):
         if impl not in ("jnp", "pallas", "sharded"):
             raise ValueError(f"unknown impl {impl!r}")
         # Injected per-plan-key compile/run timer (duck-typed — see
@@ -100,7 +101,7 @@ class DecoderSession:
         # Device-resident slot tables, uploaded once.
         self._luts = _luts(model, packed_lut)
         self.executor = make_executor(
-            impl, model, packed_lut, self._luts, interpret=interpret,
+            impl, model, packed_lut, self._luts,
             rows_per_block=rows_per_block, mesh=mesh, layout=layout,
             policy=self.policy)
         self._exec: dict[tuple, object] = {}

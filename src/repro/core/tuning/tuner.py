@@ -186,8 +186,7 @@ class Autotuner:
 
     def __init__(self, model=None, *, impl: str = "jnp",
                  layout: str = "auto", repeats: int = 3, max_probes: int = 4,
-                 n_splits: int = 16, seed: int = 7, platform: str | None = None,
-                 interpret: bool = True):
+                 n_splits: int = 16, seed: int = 7, platform: str | None = None):
         if model is None:
             from ..rans import RansParams, StaticModel
             rng = np.random.default_rng(seed)
@@ -202,7 +201,6 @@ class Autotuner:
         self.max_probes = max(int(max_probes), 2)
         self.n_splits = n_splits
         self.seed = seed
-        self.interpret = interpret
         if platform is None:
             import jax
             platform = jax.default_backend()
@@ -236,7 +234,7 @@ class Autotuner:
     def _session(self, policy: BucketPolicy, **kw):
         from ..engine.session import DecoderSession
         return DecoderSession(self.model, impl=self.impl, layout=self.layout,
-                              interpret=self.interpret, policy=policy, **kw)
+                              policy=policy, **kw)
 
     # ------------------------------------------------------------------
     # Observe
@@ -317,7 +315,7 @@ class Autotuner:
     # Pallas block sweep
     # ------------------------------------------------------------------
 
-    def sweep_rows_per_block(self, candidates=(4, 8, 16),
+    def sweep_rows_per_block(self, candidates=(8, 16, 32),
                              probe_symbols: int = 4096) -> dict:
         """ROWS*PACK grid factor sweep.  On a real accelerator each
         candidate is timed (and counts as a measurement); in interpret
@@ -330,8 +328,8 @@ class Autotuner:
         results = {}
         for rpb in candidates:
             sess = DecoderSession(self.model, impl="pallas",
-                                  interpret=not timed, rows_per_block=rpb,
-                                  layout=self.layout, policy="legacy")
+                                  rows_per_block=rpb, layout=self.layout,
+                                  policy="legacy")
             ds = sess.upload_stream(req["enc"].stream)
             out = np.asarray(sess.decode_batch(req["batch"], ds, req["n"]))
             if not (out == req["syms"]).all():

@@ -8,7 +8,7 @@ Handles the host-side data plumbing around the kernel:
                        block's worst-case word consumption (kernel VMEM bound;
                        see rans_decode.py header), built with one vectorized
                        strided gather, with slab-relative ``q0``;
-  * scatter          — kernel emits (rows, T, 128) symbols (-1 = not kept);
+  * scatter          — kernel emits (T, rows, 128) symbols (-1 = not kept);
                        positions are reconstructed closed-form from
                        ``g_hi - t`` and scattered into the flat output ON
                        DEVICE (the tile never round-trips to host numpy).
@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from repro.core.rans import StaticModel, pack_decode_lut
 from repro.core.vectorized import WalkBatch, walk_decode_batch
-from .rans_decode import LANES, walk_decode_pallas
+from .rans_decode import LANES, interpret_mode, walk_decode_pallas
 
 
 def pack_batch(batch: WalkBatch):
@@ -141,13 +141,14 @@ def _luts(model: StaticModel, packed: bool):
 
 
 def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
-           n_symbols: int, *, impl: str = "pallas", interpret: bool = True,
-           rows_per_block: int = 8, packed_lut: bool | None = None,
-           check: bool = True) -> jax.Array:
+           n_symbols: int, *, impl: str = "pallas", rows_per_block: int = 8,
+           packed_lut: bool | None = None, check: bool = True) -> jax.Array:
     """Decode a planned WalkBatch into the flat symbol device array.
 
     ``packed_lut=None`` (auto) uses the §4.4 packed LUT whenever the model
     fits it (8-bit symbols, n <= 12); the result is bit-identical either way.
+    The kernel runs as the platform allows (:func:`interpret_mode`): this
+    is the pointer-layout walk, so it runs on CPU only.
     ``check`` asserts full output coverage (one device reduction + a host
     sync; matches the jnp path's behavior — the engine's fused path skips
     it to stay sync-free).
@@ -179,7 +180,7 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
         jnp.asarray(packed["stop"]), jnp.asarray(packed["keep_lo"]),
         jnp.asarray(packed["keep_hi"]),
         n_bits=model.params.n_bits, ways=batch.ways, n_steps=batch.n_steps,
-        rows_per_block=rows_per_block, interpret=interpret)
+        rows_per_block=rows_per_block, interpret=interpret_mode())
     flat = scatter_outputs(out, jnp.asarray(per_split["g_hi"]),
                            jnp.asarray(per_split["out_base"]),
                            ways=batch.ways, pack=pack, n_symbols=n_symbols)
@@ -192,17 +193,17 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
 @functools.partial(jax.jit, static_argnames=("ways", "pack", "n_symbols"))
 def scatter_outputs(out_tiles: jax.Array, g_hi: jax.Array, out_base: jax.Array,
                     *, ways: int, pack: int, n_symbols: int) -> jax.Array:
-    """(rows, T, 128) kernel tiles -> flat decoded symbols, on device.
+    """(T, rows, 128) kernel tiles -> flat decoded symbols, on device.
 
     The closed-form position reconstruction of ``_walk_batch_jit``: kept
     positions are unique by construction, non-kept lanes scatter out of
-    bounds and are removed by ``mode="drop"`` — the (rows, T, 128) tile is
+    bounds and are removed by ``mode="drop"`` — the (T, rows, 128) tile is
     never materialized on host.
     """
-    rows, T, L = out_tiles.shape
+    T, rows, L = out_tiles.shape
     S_pad = rows * pack
-    # (rows, T, pack, W) -> (S_pad, T, W)
-    tiles = out_tiles.reshape(rows, T, pack, ways).transpose(0, 2, 1, 3)
+    # (T, rows, pack, W) -> (S_pad, T, W)
+    tiles = out_tiles.reshape(T, rows, pack, ways).transpose(1, 2, 0, 3)
     tiles = tiles.reshape(S_pad, T, ways)
     t = jnp.arange(T, dtype=jnp.int32)
     lane = jnp.arange(ways, dtype=jnp.int32)
@@ -224,7 +225,7 @@ def decode_tiles_fused(slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi,
                        pack: int, n_symbols: int) -> jax.Array:
     """Pallas walk + on-device scatter as ONE executable — the unit the
     decode engine AOT-compiles and caches per shape bucket (DESIGN.md §4):
-    the (rows, T, 128) tile lives only between the two fused stages."""
+    the (T, rows, 128) tile lives only between the two fused stages."""
     out, _qf = walk_decode_pallas(
         slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi, start, stop,
         keep_lo, keep_hi, n_bits=n_bits, ways=ways, n_steps=n_steps,

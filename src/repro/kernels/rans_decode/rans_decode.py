@@ -12,16 +12,35 @@ On TPU the natural unit is the (8, 128) VPU vector tile, so we:
     ``ROWS * PACK`` splits on a (ROWS, 128) tile;
   * replace the warp ballot + prefix used by CUDA for read offsets with a
     segmented reversed cumsum over the lane axis (VPU-friendly);
-  * keep the slot->(symbol, f, F) tables (<= 3 * 2^n * 4 B = 768 KiB at
-    n = 16) and the stream slab resident in VMEM.
+  * keep the slot->(symbol, f, F) tables resident in VMEM as lane-major
+    ``(rows, 128)`` tiles.  Mosaic lowers a gather only within one vector
+    tile, so a lookup gathers each table row along the lanes with
+    ``slot % 128`` and selects row ``slot // 128`` (16 lane gathers for the
+    packed n = 11 table).
 
-Stream residency: each grid block receives a per-block *slab* of the stream
+Step layout: the output block is ``(T, ROWS, 128)`` with the walk step on
+the leading axis, so step ``t`` writes ``out_ref[t]`` — a whole-tile store.
+
+Stream residency: each grid block's stream window is a per-block *slab*
 (host re-layout, ``ops.build_slabs``) sized to the worst-case consumption of
-its splits, so VMEM never needs the full bitstream — this mirrors the HBM ->
-VMEM DMA streaming a production kernel would issue and bounds the VMEM
-working set to
+its splits, so the kernel never needs the full bitstream.
 
-    ROWS*128*4 B (states) + slab_words*4 B + LUTs + out tile.
+  * Symbol layout (:func:`_walk_kernel_symbol`): a lane's word is a
+    closed-form function of its own walk index, so the wrapper gathers every
+    step's words into walk order with one XLA gather before the kernel, and
+    the kernel reads ``words_ref[t]`` — no gather from the stream in the
+    kernel.  This is the layout every ingested content serves under.
+  * Pointer layout (:func:`_walk_kernel`): the word index depends on the
+    walk state (``q`` minus the read offset), so the kernel gathers it from
+    the block's slab every step.  Mosaic refuses that 1-D gather and the
+    lane ``cumsum`` of the read offsets (:data:`POINTER_KERNEL_REFUSAL`),
+    so this kernel runs only interpreted, on CPU; ``PallasExecutor.plan``
+    refuses pointer content on a TPU.
+
+VMEM: every block is double-buffered and the output and word blocks span
+the whole walk, so a symbol-layout block needs about ``4 * T * ROWS * 128 *
+4`` bytes.  ``PallasExecutor.plan`` refuses a walk over
+:data:`VMEM_LIMIT_BYTES` (:func:`check_vmem`, ROADMAP S5/R3).
 
 Walk-step recurrences are exactly :func:`repro.core.vectorized._walk_one_split`
 (the jnp oracle these kernels are tested against, see ref.py):
@@ -42,10 +61,98 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128  # TPU VPU lane width
+
+#: Scoped VMEM the walk kernels ask Mosaic for.  A TPU v5e core has 128 MiB
+#: of VMEM; the rest is left to the compiler's internal scratch.
+VMEM_LIMIT_BYTES = 96 * 2 ** 20
+
+#: What Mosaic says when it compiles :func:`_walk_kernel` for a TPU v5e.
+POINTER_KERNEL_REFUSAL = (
+    "the pointer-layout Pallas walk does not compile for TPU: Mosaic "
+    "refuses its read-offset prefix sum ('Unimplemented primitive in Pallas "
+    "TPU lowering for KernelType.TC: cumsum'), and its per-lane stream-word "
+    "gather from a 1-D slab is not a gather Mosaic lowers ('Only 2D gather "
+    "is supported'); register the content with an emission log (symbol "
+    "layout) or decode it with impl='jnp'")
+
+
+def interpret_mode() -> bool:
+    """How the walk kernels run on the current backend: interpreted on CPU,
+    compiled by Mosaic on TPU.  No other platform has a walk kernel."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas walk kernel runs on 'tpu' (compiled) or 'cpu' "
+        f"(interpreted); the default backend is {backend!r}")
+
+
+def check_vmem(n_steps: int, rows_per_block: int, lut_words: int) -> None:
+    """Raise when a symbol-layout walk's grid block does not fit
+    :data:`VMEM_LIMIT_BYTES`.  Every block is double-buffered: the
+    (T, ROWS, 128) words and output, the slot tables and the nine
+    (ROWS, 128) per-lane tiles."""
+    tile = rows_per_block * LANES * 4
+    need = 2 * (2 * n_steps * tile + 4 * lut_words + 9 * tile)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"a Pallas walk of {n_steps} steps x {rows_per_block} rows needs "
+            f"{need} B of VMEM per block, over the kernel's "
+            f"VMEM_LIMIT_BYTES = {VMEM_LIMIT_BYTES} B (the output block "
+            f"spans the whole walk); decode this capability with impl='jnp'")
+
+
+def lut_tiles(lut: jax.Array) -> jax.Array:
+    """1-D slot table -> zero-padded lane-major ``(rows, 128)`` tile."""
+    n = lut.shape[0]
+    rows = -(-n // LANES)
+    return jnp.pad(lut.astype(jnp.int32),
+                   (0, rows * LANES - n)).reshape(rows, LANES)
+
+
+def _lut_lookup(lut_refs, slot):
+    """``tab[slot]`` for each ``(rows, 128)`` table ref: every table row is
+    gathered along the lanes with ``slot % 128`` (a gather Mosaic lowers)
+    and the lane keeps the row ``slot // 128``."""
+    lo = slot & (LANES - 1)
+    hi = slot >> 7                       # slot // LANES
+    n_rows = lut_refs[0].shape[0]
+
+    def body(j, accs):
+        hit = hi == j
+        return tuple(
+            jnp.where(hit, jnp.take_along_axis(
+                jnp.broadcast_to(ref[pl.ds(j, 1), :], slot.shape), lo,
+                axis=1, mode="promise_in_bounds"), acc)
+            for ref, acc in zip(lut_refs, accs))
+
+    init = tuple(jnp.zeros(slot.shape, jnp.int32) for _ in lut_refs)
+    return jax.lax.fori_loop(0, n_rows, body, init, unroll=n_rows <= 32)
+
+
+def _kernel_slot_decode(lut_refs, slot):
+    """slot -> (symbol, f, F) from the VMEM-resident tables — the §4.4
+    packed single-int32 unpack (sym[0:8] | f[8:20] | F[20:32]) when one
+    table is given, else three split tables.  Shared by the pointer and
+    symbol-layout kernels; the jnp walks' array-based twin is
+    ``vectorized._slot_decode``."""
+    vals = _lut_lookup(lut_refs, slot)
+    if len(vals) == 1:
+        pw = vals[0].astype(jnp.uint32)
+        s = (pw & jnp.uint32(0xFF)).astype(jnp.int32)
+        fs = (pw >> jnp.uint32(8)) & jnp.uint32(0xFFF)
+        Fs = (pw >> jnp.uint32(20)) & jnp.uint32(0xFFF)
+    else:
+        s, fs, Fs = vals
+        fs = fs.astype(jnp.uint32)
+        Fs = Fs.astype(jnp.uint32)
+    return s, fs, Fs
 
 
 def _segment_read_offsets(reads: jax.Array, ways: int):
@@ -73,43 +180,21 @@ def _segment_read_offsets(reads: jax.Array, ways: int):
     return suffix_excl, seg_total
 
 
-def _kernel_slot_decode(sym_ref, f_ref, F_ref, slot, packed: bool):
-    """slot -> (symbol, f, F) from VMEM-resident tables — the §4.4 packed
-    single-int32 unpack (sym[0:8] | f[8:20] | F[20:32]) or three split
-    gathers.  Shared by the pointer and symbol-layout kernels; the jnp
-    walks' array-based twin is ``vectorized._slot_decode``."""
-    if packed:
-        pw = jnp.take(sym_ref[...], slot).astype(jnp.uint32)
-        s = (pw & jnp.uint32(0xFF)).astype(jnp.int32)
-        fs = (pw >> jnp.uint32(8)) & jnp.uint32(0xFFF)
-        Fs = (pw >> jnp.uint32(20)) & jnp.uint32(0xFFF)
-    else:
-        s = jnp.take(sym_ref[...], slot)
-        fs = jnp.take(f_ref[...], slot).astype(jnp.uint32)
-        Fs = jnp.take(F_ref[...], slot).astype(jnp.uint32)
-    return s, fs, Fs
-
-
 def _walk_kernel(stream_ref, *refs, n_bits: int, ways: int, n_steps: int,
-                 packed: bool):
+                 n_luts: int):
     """One grid step: walk ``n_steps`` symbol groups for a (ROWS, 128) tile.
 
-    ``packed`` selects the §4.4 single-table LUT: ``sym_ref`` then holds the
-    packed int32 slot words (symbol | f << 8 | F << 20) and the per-step
-    table access is ONE VMEM gather instead of three.
+    ``n_luts == 1`` selects the §4.4 single-table LUT: the table then holds
+    the packed int32 slot words (symbol | f << 8 | F << 20).
     """
-    if packed:
-        (sym_ref, k_ref, y_ref, x0_ref, q0_ref, ghi_ref, start_ref,
-         stop_ref, klo_ref, khi_ref, out_ref, qf_ref) = refs
-        f_ref = F_ref = None
-    else:
-        (sym_ref, f_ref, F_ref, k_ref, y_ref, x0_ref, q0_ref, ghi_ref,
-         start_ref, stop_ref, klo_ref, khi_ref, out_ref, qf_ref) = refs
+    lut_refs = refs[:n_luts]
+    (k_ref, y_ref, x0_ref, q0_ref, ghi_ref, start_ref, stop_ref, klo_ref,
+     khi_ref, out_ref, qf_ref) = refs[n_luts:]
     L_bound = jnp.uint32(1 << 16)
     b_bits = jnp.uint32(16)
     slot_mask = jnp.uint32((1 << n_bits) - 1)
     rows, L = k_ref.shape
-    lane_in_seg = (jax.lax.iota(jnp.int32, L) % ways)[None, :]
+    lane_in_seg = jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1) & (ways - 1)
 
     k = k_ref[...]
     y = y_ref[...].astype(jnp.uint32)
@@ -118,7 +203,7 @@ def _walk_kernel(stream_ref, *refs, n_bits: int, ways: int, n_steps: int,
     keep_lo = klo_ref[...]
     keep_hi = khi_ref[...]
     g_hi = ghi_ref[...]
-    stream = stream_ref[0]  # block spec delivers (1, slab_words)
+    stream = stream_ref[0, 0]  # block spec delivers (1, 1, slab_words)
 
     def step(t, carry):
         x, q = carry
@@ -128,7 +213,7 @@ def _walk_kernel(stream_ref, *refs, n_bits: int, ways: int, n_steps: int,
         recon = active & (i == k)
         dec = active & (i < k)
         slot = (x & slot_mask).astype(jnp.int32)
-        s, fs, Fs = _kernel_slot_decode(sym_ref, f_ref, F_ref, slot, packed)
+        s, fs, Fs = _kernel_slot_decode(lut_refs, slot)
         x_dec = fs * (x >> jnp.uint32(n_bits)) + (slot.astype(jnp.uint32) - Fs)
         under = x_dec < L_bound
         reads = recon | (dec & under)
@@ -140,39 +225,32 @@ def _walk_kernel(stream_ref, *refs, n_bits: int, ways: int, n_steps: int,
         x_new = jnp.where(recon, x_recon, jnp.where(dec, x_dec2, x))
         q_new = q - seg_total
         keep = dec & (i >= keep_lo) & (i < keep_hi)
-        pl.store(out_ref, (slice(None), pl.dslice(t, 1), slice(None)),
-                 jnp.where(keep, s, -1)[:, None, :])
+        out_ref[t] = jnp.where(keep, s, -1)
         return (x_new, q_new)
 
     x0 = x0_ref[...].astype(jnp.uint32)
     q0 = q0_ref[...]
-    xf, qf = jax.lax.fori_loop(0, n_steps, step, (x0, q0))
+    _xf, qf = jax.lax.fori_loop(0, n_steps, step, (x0, q0))
     qf_ref[...] = qf
 
 
-def _walk_kernel_symbol(slab_ref, *refs, n_bits: int, ways: int,
-                        n_steps: int, packed: bool):
+def _walk_kernel_symbol(words_ref, *refs, n_bits: int, ways: int,
+                        n_steps: int, n_luts: int):
     """Pointer-free grid step (symbol-indexed layout, DESIGN.md §9).
 
-    ``slab_ref`` holds the block's window of the ``words_by_symbol``
-    permutation: lane l of segment j fetches ``slab[i + sym_rel]`` where
-    ``i`` is its own walk symbol index — so the warp-ballot/cumsum read
-    -offset machinery of :func:`_walk_kernel` disappears entirely and the
-    carry is just the lane states.  On the VPU this removes the only
-    cross-lane dependency in the step.
+    ``words_ref[t]`` holds the (ROWS, 128) words the lanes read at step
+    ``t`` (gathered into walk order by :func:`_walk_order_words`), so the
+    warp-ballot/cumsum read-offset machinery of :func:`_walk_kernel` and
+    every stream gather disappear and the carry is just the lane states.
     """
-    if packed:
-        (sym_ref, k_ref, y_ref, x0_ref, symb_ref, ghi_ref, start_ref,
-         stop_ref, klo_ref, khi_ref, out_ref) = refs
-        f_ref = F_ref = None
-    else:
-        (sym_ref, f_ref, F_ref, k_ref, y_ref, x0_ref, symb_ref, ghi_ref,
-         start_ref, stop_ref, klo_ref, khi_ref, out_ref) = refs
+    lut_refs = refs[:n_luts]
+    (k_ref, y_ref, x0_ref, ghi_ref, start_ref, stop_ref, klo_ref, khi_ref,
+     out_ref) = refs[n_luts:]
     L_bound = jnp.uint32(1 << 16)
     b_bits = jnp.uint32(16)
     slot_mask = jnp.uint32((1 << n_bits) - 1)
     rows, L = k_ref.shape
-    lane_in_seg = (jax.lax.iota(jnp.int32, L) % ways)[None, :]
+    lane_in_seg = jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1) & (ways - 1)
 
     k = k_ref[...]
     y = y_ref[...].astype(jnp.uint32)
@@ -181,8 +259,6 @@ def _walk_kernel_symbol(slab_ref, *refs, n_bits: int, ways: int,
     keep_lo = klo_ref[...]
     keep_hi = khi_ref[...]
     g_hi = ghi_ref[...]
-    sym_rel = symb_ref[...]
-    wbs = slab_ref[0]  # block spec delivers (1, slab_words)
 
     def step(t, x):
         g = g_hi - t
@@ -191,20 +267,44 @@ def _walk_kernel_symbol(slab_ref, *refs, n_bits: int, ways: int,
         recon = active & (i == k)
         dec = active & (i < k)
         slot = (x & slot_mask).astype(jnp.int32)
-        s, fs, Fs = _kernel_slot_decode(sym_ref, f_ref, F_ref, slot, packed)
+        s, fs, Fs = _kernel_slot_decode(lut_refs, slot)
         x_dec = fs * (x >> jnp.uint32(n_bits)) + (slot.astype(jnp.uint32) - Fs)
         under = x_dec < L_bound
-        idx = jnp.clip(i + sym_rel, 0, wbs.shape[0] - 1)
-        word = jnp.take(wbs, idx).astype(jnp.uint32)
+        word = words_ref[t].astype(jnp.uint32)
         x_recon = (y << b_bits) | word
         x_dec2 = jnp.where(under, (x_dec << b_bits) | word, x_dec)
         x_new = jnp.where(recon, x_recon, jnp.where(dec, x_dec2, x))
         keep = dec & (i >= keep_lo) & (i < keep_hi)
-        pl.store(out_ref, (slice(None), pl.dslice(t, 1), slice(None)),
-                 jnp.where(keep, s, -1)[:, None, :])
+        out_ref[t] = jnp.where(keep, s, -1)
         return x_new
 
     jax.lax.fori_loop(0, n_steps, step, x0_ref[...].astype(jnp.uint32))
+
+
+def _walk_order_words(slabs: jax.Array, sym_rel: jax.Array, g_hi: jax.Array,
+                      *, ways: int, n_steps: int,
+                      rows_per_block: int) -> jax.Array:
+    """(T, rows, 128) symbol-layout words in walk order: at step ``t`` lane
+    ``l`` of row ``r`` reads ``slab[i + sym_rel]`` of its block, where
+    ``i = (g_hi - t) * ways + l % ways`` is its walk index."""
+    n_rows, L = g_hi.shape
+    t = jnp.arange(n_steps, dtype=jnp.int32)[:, None, None]
+    lane = (jnp.arange(L, dtype=jnp.int32) % ways)[None, None, :]
+    idx = jnp.clip((g_hi[None] - t) * ways + lane + sym_rel[None], 0,
+                   slabs.shape[1] - 1)
+    block = (jnp.arange(n_rows, dtype=jnp.int32) // rows_per_block)
+    return slabs[block[None, :, None], idx]
+
+
+def _lut_args(sym_lut, f_lut, F_lut) -> tuple:
+    packed = f_lut is None
+    assert (F_lut is None) == packed, "pass both f_lut and F_lut or neither"
+    return tuple(lut_tiles(a) for a in
+                 ((sym_lut,) if packed else (sym_lut, f_lut, F_lut)))
+
+
+def _full_spec(arr) -> pl.BlockSpec:
+    return pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
 
 
 @functools.partial(
@@ -217,42 +317,37 @@ def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
                               g_hi: jax.Array, start: jax.Array,
                               stop: jax.Array, keep_lo: jax.Array,
                               keep_hi: jax.Array, *, n_bits: int, ways: int,
-                              n_steps: int, rows_per_block: int = 8,
-                              interpret: bool = True):
+                              n_steps: int, rows_per_block: int,
+                              interpret: bool):
     """pallas_call wrapper for the symbol-indexed walk.  ``slabs`` is the
     per-block window of ``words_by_symbol`` with ``sym_rel`` already
     slab-relative; everything else matches :func:`walk_decode_pallas`
-    minus the stream pointer (no ``q0``, no ``qf`` output)."""
-    packed = f_lut is None
-    assert (F_lut is None) == packed, "pass both f_lut and F_lut or neither"
-    n_rows, L = k.shape
-    assert L == LANES and n_rows % rows_per_block == 0
-    n_blocks = n_rows // rows_per_block
-    assert slabs.shape[0] == n_blocks
-    slab_words = slabs.shape[1]
-    R = rows_per_block
+    minus the stream pointer (no ``q0``, no ``qf`` output).
 
-    grid = (n_blocks,)
+    Returns int32 (n_steps, n_rows, 128), -1 where not kept.
+    """
+    luts = _lut_args(sym_lut, f_lut, F_lut)
+    n_rows, L = k.shape
+    R = rows_per_block
+    assert L == LANES and n_rows % R == 0
+    assert slabs.shape[0] == n_rows // R
+    words = _walk_order_words(slabs, sym_rel, g_hi, ways=ways,
+                              n_steps=n_steps, rows_per_block=R)
     row_spec = pl.BlockSpec((R, L), lambda b: (b, 0))
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
+    step_spec = pl.BlockSpec((n_steps, R, L), lambda b: (0, b, 0))
     kernel = functools.partial(_walk_kernel_symbol, n_bits=n_bits, ways=ways,
-                               n_steps=n_steps, packed=packed)
-    lut_args = (sym_lut,) if packed else (sym_lut, f_lut, F_lut)
-    out = pl.pallas_call(
+                               n_steps=n_steps, n_luts=len(luts))
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, slab_words), lambda b: (b, 0)),  # permutation
-            *[full(a) for a in lut_args],
-            row_spec, row_spec, row_spec, row_spec, row_spec, row_spec,
-            row_spec, row_spec, row_spec,
-        ],
-        out_specs=pl.BlockSpec((R, n_steps, L), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_rows, n_steps, L), jnp.int32),
+        grid=(n_rows // R,),
+        in_specs=[step_spec, *[_full_spec(a) for a in luts],
+                  *[row_spec] * 8],
+        out_specs=step_spec,
+        out_shape=jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(slabs, *lut_args, k, y, x0, sym_rel, g_hi,
-      start, stop, keep_lo, keep_hi)
-    return out
+    )(words, *luts, k, y, x0, g_hi, start, stop, keep_lo, keep_hi)
 
 
 @functools.partial(
@@ -264,8 +359,7 @@ def walk_decode_pallas(slabs: jax.Array, sym_lut: jax.Array,
                        x0: jax.Array, q0: jax.Array, g_hi: jax.Array,
                        start: jax.Array, stop: jax.Array, keep_lo: jax.Array,
                        keep_hi: jax.Array, *, n_bits: int, ways: int,
-                       n_steps: int, rows_per_block: int = 8,
-                       interpret: bool = True):
+                       n_steps: int, rows_per_block: int, interpret: bool):
     """pallas_call wrapper.  All per-split arrays are lane-packed to
     (n_rows, 128) by :mod:`.ops`; ``slabs`` is (n_blocks, slab_words) — the
     per-block stream slab with ``q0`` already slab-relative.
@@ -273,41 +367,34 @@ def walk_decode_pallas(slabs: jax.Array, sym_lut: jax.Array,
     ``f_lut=F_lut=None`` selects the packed-LUT kernel: ``sym_lut`` must then
     be the :func:`repro.core.rans.pack_decode_lut` int32 table.
 
-    Returns (out, qf): out is int32 (n_rows, n_steps, 128), -1 where not kept.
+    Returns (out, qf): out is int32 (n_steps, n_rows, 128), -1 where not
+    kept.
     """
-    packed = f_lut is None
-    assert (F_lut is None) == packed, "pass both f_lut and F_lut or neither"
+    luts = _lut_args(sym_lut, f_lut, F_lut)
     n_rows, L = k.shape
-    assert L == LANES and n_rows % rows_per_block == 0
-    n_blocks = n_rows // rows_per_block
-    assert slabs.shape[0] == n_blocks
-    slab_words = slabs.shape[1]
     R = rows_per_block
-
-    grid = (n_blocks,)
+    assert L == LANES and n_rows % R == 0
+    n_blocks, slab_words = slabs.shape
+    assert n_blocks == n_rows // R
     row_spec = pl.BlockSpec((R, L), lambda b: (b, 0))
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
+    step_spec = pl.BlockSpec((n_steps, R, L), lambda b: (0, b, 0))
     kernel = functools.partial(_walk_kernel, n_bits=n_bits, ways=ways,
-                               n_steps=n_steps, packed=packed)
-    lut_args = (sym_lut,) if packed else (sym_lut, f_lut, F_lut)
-    out, qf = pl.pallas_call(
+                               n_steps=n_steps, n_luts=len(luts))
+    return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, slab_words), lambda b: (b, 0)),  # stream slab
-            *[full(a) for a in lut_args],
-            row_spec, row_spec, row_spec, row_spec, row_spec, row_spec,
-            row_spec, row_spec, row_spec,
+            pl.BlockSpec((1, 1, slab_words), lambda b: (b, 0, 0)),
+            *[_full_spec(a) for a in luts],
+            *[row_spec] * 9,
         ],
-        out_specs=[
-            pl.BlockSpec((R, n_steps, L), lambda b: (b, 0, 0)),
-            row_spec,
-        ],
+        out_specs=[step_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((n_rows, n_steps, L), jnp.int32),
+            jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
             jax.ShapeDtypeStruct((n_rows, L), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(slabs, *lut_args, k, y, x0, q0, g_hi,
+    )(slabs.reshape(n_blocks, 1, slab_words), *luts, k, y, x0, q0, g_hi,
       start, stop, keep_lo, keep_hi)
-    return out, qf

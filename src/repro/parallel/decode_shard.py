@@ -27,8 +27,8 @@ across every device of a mesh with ``shard_map``:
     mesh axes merges the per-shard outputs exactly — every position is
     written by one shard and -1 everywhere else;
   * the merged output is replicated (``out_specs=P()``; the pmax makes the
-    shards identical, ``check_rep=False`` because shard_map cannot prove
-    that statically on this jax version).
+    shards identical, ``check_vma=False`` because shard_map cannot prove
+    that statically through the walk's scatter).
 
 Bucketing: the split-row bucket is ``n_shards * work_bucket(ceil(S /
 n_shards))`` so every shard gets the same inert-padded row count and any
@@ -51,7 +51,6 @@ import weakref
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine.executors import JnpExecutor, _check_sym_alignment
@@ -236,10 +235,10 @@ class ShardedExecutor(JnpExecutor):
                     ctx_of_index=None)
                 return jax.lax.pmax(out, axes)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(axes, None), P(), P(), P()) + (P(axes),) * 10,
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         return jax.jit(sharded).lower(*plan.args).compile()
 
     def run(self, exe, plan: DecodePlan) -> jax.Array:

@@ -1,19 +1,23 @@
 """Pallas rANS decode kernel: shape/dtype sweeps vs the pure-jnp oracle.
 
 The algorithm is integer-exact, so comparisons are equality (assert_allclose
-with zero tolerance).  Kernels run in interpret mode (CPU container; TPU is
-the compile target — see DESIGN.md §2).
+with zero tolerance).  On CPU the kernels run in interpret mode, as the
+platform decides (TPU is the compile target — see DESIGN.md §2;
+tests/test_tpu_compile.py compiles them for a described v5e).
 """
 
+import jax
 import numpy as np
 import pytest
 from numpy.testing import assert_allclose
 
+from repro.core.engine import DecoderSession, with_symbol_layout
 from repro.core.rans import RansParams, StaticModel
 from repro.core import conventional, recoil
 from repro.core.recoil import build_split_states
 from repro.core.vectorized import WalkBatch, encode_interleaved_fast
 from repro.kernels.rans_decode import decode, decode_recoil_kernel
+from repro.kernels.rans_decode.rans_decode import interpret_mode
 from repro.kernels.rans_decode.ref import decode_reference, walk_reference
 
 
@@ -134,3 +138,49 @@ def test_packed_lut_rejected_when_it_cannot_fit():
     with pytest.raises(ValueError, match="packed LUT"):
         decode(batch, enc.stream, model, plan.n_symbols, impl="pallas",
                packed_lut=True)
+
+
+# ----------------------------------------------------------------------
+# Platform-derived mode and plan-time refusals (no option selects them:
+# the tests steer the platform the code observes)
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend, interpret", [("cpu", True),
+                                                ("tpu", False)])
+def test_interpret_mode_follows_platform(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert interpret_mode() is interpret
+
+
+def test_interpret_mode_refuses_other_platforms(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        interpret_mode()
+
+
+def test_pallas_plan_refuses_pointer_content_on_tpu(monkeypatch):
+    """On a TPU the pointer walk has no kernel Mosaic compiles: the plan
+    raises with the compiler's reason instead of switching backends."""
+    syms, model, enc = _make(n=5_000)
+    plan = recoil.plan_splits(enc, 4)
+    batch = WalkBatch.from_splits(
+        build_split_states(plan, enc.final_states), plan.ways)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sess = DecoderSession(model, impl="pallas")
+    assert sess.executor.interpret is False
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        sess.prepare(batch, enc.stream, plan.n_symbols)
+
+
+def test_pallas_plan_refuses_walk_over_vmem_limit():
+    """A one-split walk of ~6.6k steps needs a whole-walk output block
+    over the kernel's VMEM limit: the plan names the limit."""
+    syms, model, enc = _make(n=210_000)
+    plan = recoil.plan_splits(enc, 1)
+    batch = WalkBatch.from_splits(
+        build_split_states(plan, enc.final_states), plan.ways)
+    sess = DecoderSession(model, impl="pallas")
+    ds = with_symbol_layout(sess.upload_stream(enc.stream), enc.k_of_word,
+                            plan.n_symbols)
+    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
+        sess.prepare(batch, ds, plan.n_symbols)
