@@ -257,8 +257,8 @@ def _bench_microbatch(model: StaticModel, repeats: int) -> dict:
         "speedup": round(seq_s / coal_s, 2),
         "recompiles_warm": svc.stats.compiles - compiles_before,
         "service_stats": svc.stats.snapshot(),
-        # Per-plan-key compile/run split (DESIGN.md §13) — where the warm
-        # microbatch wall time actually goes.
+        # Per-plan-key compile times (DESIGN.md §13): which shapes paid
+        # compilation.
         "profiler": svc.obs.profiler.snapshot(top=4),
     }
 

@@ -157,9 +157,9 @@ class EncoderSession:
     def __init__(self, model, *, impl: str = "jnp", window: int = 96,
                  fast_rounds: bool = True, policy=None,
                  resume_capacity: int = 64, profiler=None):
-        # Injected per-plan-key compile/run timer (duck-typed, shared with
-        # the decode session under session="encode"; core never imports
-        # runtime).  None keeps execute() free of timing branches.
+        # Injected per-plan-key compile timer (duck-typed, shared with the
+        # decode session under session="encode"; core never imports
+        # runtime).  None keeps compiles free of timing branches.
         self.profiler = profiler
         self.model = model
         self.adaptive = np.asarray(model.f).ndim == 2
@@ -229,17 +229,8 @@ class EncoderSession:
         return out, cap
 
     def _run(self, plan: EncodePlan, rounds: int, cap: int):
-        """One tier dispatch, run-timed per plan key when profiled (the
-        encode pipeline reads its flags on the host right after, so these
-        run times are true walls, not dispatch costs)."""
-        exe = self._executable(plan, rounds, cap)
-        prof = self.profiler
-        if prof is None:
-            return self.executor.run(exe, plan)
-        t0 = prof.now()
-        out = self.executor.run(exe, plan)
-        prof.record_run("encode", plan.key + (rounds, cap), prof.now() - t0)
-        return out
+        """One tier dispatch (compiling the tier's executable on a miss)."""
+        return self.executor.run(self._executable(plan, rounds, cap), plan)
 
     def _executable(self, plan: EncodePlan, rounds: int, words_bucket: int):
         key = plan.key + (rounds, words_bucket)

@@ -209,7 +209,7 @@ class JnpExecutor(Executor):
                     *(arrs[f] for f in SPLIT_FIELDS))
         return DecodePlan(key=key, args=args, statics=statics,
                           n_symbols=n_symbols, out_bucket=out_b,
-                          layout=layout)
+                          walk_slots=s_b * W * steps_b, layout=layout)
 
     def lower(self, plan: DecodePlan):
         jitted = (_walk_batch_symbol_jit if plan.layout == "symbol"
@@ -334,6 +334,7 @@ class PallasExecutor(Executor):
                     jnp.asarray(per_split["out_base"]))
             return DecodePlan(key=key, args=args, statics=statics,
                               n_symbols=n_symbols, out_bucket=out_b,
+                              walk_slots=steps_b * rows * LANES,
                               layout=layout)
         host_words = self._host_words(ds)
         slabs, slab_lo = build_slabs(host_words, per_split, rows, pack, rpb)
@@ -354,7 +355,7 @@ class PallasExecutor(Executor):
                 jnp.asarray(per_split["out_base"]))
         return DecodePlan(key=key, args=args, statics=statics,
                           n_symbols=n_symbols, out_bucket=out_b,
-                          layout=layout)
+                          walk_slots=steps_b * rows * LANES, layout=layout)
 
     def lower(self, plan: DecodePlan):
         from repro.kernels.rans_decode.ops import (decode_tiles_fused,

@@ -18,7 +18,10 @@ A :class:`DecodePlan` captures everything the executable call needs:
   * ``statics``  — the static lowering kwargs (``n_steps``, ``n_symbols``
                    etc. at their *bucketed* values);
   * ``n_symbols``— the real output length; the bucket tail is sliced off
-                   after the call.
+                   after the call;
+  * ``walk_slots``— the lane positions the walk executes: bucketed steps
+                   times every lane of the executor's tile, padding
+                   included (``n_symbols`` of them answer symbols).
 
 Bucketing policy (DESIGN.md §4): memory-dominant dims pad to powers of two
 (:func:`pow2_bucket`), compute-dominant dims to powers of two and their
@@ -224,6 +227,7 @@ class DecodePlan:
     statics: dict
     n_symbols: int
     out_bucket: int
+    walk_slots: int
     layout: str = "pointer"   # plan-IR layout axis (see LAYOUTS)
 
 

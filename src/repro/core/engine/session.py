@@ -5,7 +5,8 @@ The session owns exactly three things (DESIGN.md §4, §4b):
   * device-resident slot tables, uploaded once at construction;
   * the executable cache — ``plan.key -> compiled`` — so a bucket hit
     physically cannot re-trace, and ``stats.compiles`` counts builds exactly;
-  * request accounting (:class:`EngineStats`).
+  * request accounting (:class:`EngineStats`), including the walk's padded
+    slots against the symbols it answers.
 
 All backend knowledge lives in the executor (``jnp`` / ``pallas`` /
 ``sharded`` — see ``engine.executors``).  The prepare/execute split is
@@ -44,6 +45,8 @@ class EngineStats:
     compiles: int = 0      # executables built (bucket misses)
     cache_hits: int = 0    # decodes served by an existing executable
     decodes: int = 0
+    walk_slots: int = 0    # walk positions executed, padding included
+    walk_symbols: int = 0  # symbols those walks answered
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -81,9 +84,9 @@ class DecoderSession:
                  profiler=None):
         if impl not in ("jnp", "pallas", "sharded"):
             raise ValueError(f"unknown impl {impl!r}")
-        # Injected per-plan-key compile/run timer (duck-typed — see
+        # Injected per-plan-key compile timer (duck-typed — see
         # repro.runtime.observability.ExecProfiler; core never imports
-        # runtime).  None keeps execute() free of timing branches.
+        # runtime).  None keeps compiles free of timing branches.
         self.profiler = profiler
         from repro.kernels.rans_decode.ops import _luts, packed_lut_ok
         self.model = model
@@ -164,16 +167,32 @@ class DecoderSession:
         with self._lock:
             return len(self._exec)
 
+    def compiled_hlo(self) -> list[str]:
+        """The optimized HLO text of every cached executable.  Each
+        instruction's ``op_name`` metadata carries the program scopes
+        (``repro.runtime.observability.SCOPES``) a profiler trace reports
+        as the op's ``tf_op``."""
+        with self._lock:
+            exes = list(self._exec.values())
+        return [exe.as_text() for exe in exes]
+
+    def walk_totals(self) -> tuple[int, int]:
+        """``(walk_slots, walk_symbols)``, read together: the walk
+        positions every executed plan ran (bucketed steps over all its
+        lanes, padding included) and the symbols they answered."""
+        with self._lock:
+            return self.stats.walk_slots, self.stats.walk_symbols
+
     def execute(self, plan: DecodePlan) -> jax.Array:
         """Run a prepared plan: compile on bucket miss, else reuse.
 
         With a profiler injected, the compile (under the lock, counted
-        once per bucket miss) and the run call (outside it) are timed per
-        plan key — run time is the host-side dispatch cost unless the
-        caller syncs (see ``ExecProfiler``'s docstring)."""
+        once per bucket miss) is timed per plan key."""
         prof = self.profiler
         with self._lock:
             self.stats.decodes += 1
+            self.stats.walk_slots += plan.walk_slots
+            self.stats.walk_symbols += plan.n_symbols
             exe = self._exec.get(plan.key)
             if exe is None:
                 if prof is None:
@@ -186,12 +205,7 @@ class DecoderSession:
                 self.stats.compiles += 1
             else:
                 self.stats.cache_hits += 1
-        if prof is None:
-            return self.executor.run(exe, plan)[:plan.n_symbols]
-        t0 = prof.now()
-        out = self.executor.run(exe, plan)[:plan.n_symbols]
-        prof.record_run("decode", plan.key, prof.now() - t0)
-        return out
+        return self.executor.run(exe, plan)[:plan.n_symbols]
 
     def decode_batch(self, batch: WalkBatch, stream,
                      n_symbols: int) -> jax.Array:

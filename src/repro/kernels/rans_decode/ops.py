@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core.rans import StaticModel, pack_decode_lut
 from repro.core.vectorized import WalkBatch, walk_decode_batch
+from repro.runtime.observability import SCATTER
 from .rans_decode import LANES, interpret_mode, walk_decode_pallas
 
 
@@ -200,19 +201,21 @@ def scatter_outputs(out_tiles: jax.Array, g_hi: jax.Array, out_base: jax.Array,
     bounds and are removed by ``mode="drop"`` — the (T, rows, 128) tile is
     never materialized on host.
     """
-    T, rows, L = out_tiles.shape
-    S_pad = rows * pack
-    # (T, rows, pack, W) -> (S_pad, T, W)
-    tiles = out_tiles.reshape(T, rows, pack, ways).transpose(1, 2, 0, 3)
-    tiles = tiles.reshape(S_pad, T, ways)
-    t = jnp.arange(T, dtype=jnp.int32)
-    lane = jnp.arange(ways, dtype=jnp.int32)
-    i = ((g_hi[:, None, None].astype(jnp.int32) - t[None, :, None]) * ways
-         + lane[None, None, :] + out_base[:, None, None].astype(jnp.int32))
-    i = jnp.where(tiles >= 0, i, n_symbols)
-    outv = jnp.full((n_symbols,), -1, dtype=jnp.int32)
-    return outv.at[i.reshape(-1)].set(tiles.reshape(-1), mode="drop",
-                                      unique_indices=True)
+    with jax.named_scope(SCATTER):
+        T, rows, L = out_tiles.shape
+        S_pad = rows * pack
+        # (T, rows, pack, W) -> (S_pad, T, W)
+        tiles = out_tiles.reshape(T, rows, pack, ways).transpose(1, 2, 0, 3)
+        tiles = tiles.reshape(S_pad, T, ways)
+        t = jnp.arange(T, dtype=jnp.int32)
+        lane = jnp.arange(ways, dtype=jnp.int32)
+        i = ((g_hi[:, None, None].astype(jnp.int32) - t[None, :, None])
+             * ways + lane[None, None, :]
+             + out_base[:, None, None].astype(jnp.int32))
+        i = jnp.where(tiles >= 0, i, n_symbols)
+        outv = jnp.full((n_symbols,), -1, dtype=jnp.int32)
+        return outv.at[i.reshape(-1)].set(tiles.reshape(-1), mode="drop",
+                                          unique_indices=True)
 
 
 @functools.partial(jax.jit, static_argnames=(

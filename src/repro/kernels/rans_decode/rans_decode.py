@@ -64,6 +64,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime.observability import WALK_GATHER, WALK_KERNEL
+
 LANES = 128  # TPU VPU lane width
 
 #: Scoped VMEM the walk kernels ask Mosaic for.  A TPU v5e core has 128 MiB
@@ -287,13 +289,14 @@ def _walk_order_words(slabs: jax.Array, sym_rel: jax.Array, g_hi: jax.Array,
     """(T, rows, 128) symbol-layout words in walk order: at step ``t`` lane
     ``l`` of row ``r`` reads ``slab[i + sym_rel]`` of its block, where
     ``i = (g_hi - t) * ways + l % ways`` is its walk index."""
-    n_rows, L = g_hi.shape
-    t = jnp.arange(n_steps, dtype=jnp.int32)[:, None, None]
-    lane = (jnp.arange(L, dtype=jnp.int32) % ways)[None, None, :]
-    idx = jnp.clip((g_hi[None] - t) * ways + lane + sym_rel[None], 0,
-                   slabs.shape[1] - 1)
-    block = (jnp.arange(n_rows, dtype=jnp.int32) // rows_per_block)
-    return slabs[block[None, :, None], idx]
+    with jax.named_scope(WALK_GATHER):
+        n_rows, L = g_hi.shape
+        t = jnp.arange(n_steps, dtype=jnp.int32)[:, None, None]
+        lane = (jnp.arange(L, dtype=jnp.int32) % ways)[None, None, :]
+        idx = jnp.clip((g_hi[None] - t) * ways + lane + sym_rel[None], 0,
+                       slabs.shape[1] - 1)
+        block = (jnp.arange(n_rows, dtype=jnp.int32) // rows_per_block)
+        return slabs[block[None, :, None], idx]
 
 
 def _lut_args(sym_lut, f_lut, F_lut) -> tuple:
@@ -337,17 +340,21 @@ def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
     step_spec = pl.BlockSpec((n_steps, R, L), lambda b: (0, b, 0))
     kernel = functools.partial(_walk_kernel_symbol, n_bits=n_bits, ways=ways,
                                n_steps=n_steps, n_luts=len(luts))
-    return pl.pallas_call(
-        kernel,
-        grid=(n_rows // R,),
-        in_specs=[step_spec, *[_full_spec(a) for a in luts],
-                  *[row_spec] * 8],
-        out_specs=step_spec,
-        out_shape=jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(words, *luts, k, y, x0, g_hi, start, stop, keep_lo, keep_hi)
+    # The device op takes its HLO name from the innermost name-stack
+    # component: ``name`` keeps it the kernel's, under the layer scope.
+    with jax.named_scope(WALK_KERNEL):
+        return pl.pallas_call(
+            kernel,
+            grid=(n_rows // R,),
+            in_specs=[step_spec, *[_full_spec(a) for a in luts],
+                      *[row_spec] * 8],
+            out_specs=step_spec,
+            out_shape=jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+            name="walk_decode_symbol_pallas",
+        )(words, *luts, k, y, x0, g_hi, start, stop, keep_lo, keep_hi)
 
 
 @functools.partial(
@@ -380,21 +387,23 @@ def walk_decode_pallas(slabs: jax.Array, sym_lut: jax.Array,
     step_spec = pl.BlockSpec((n_steps, R, L), lambda b: (0, b, 0))
     kernel = functools.partial(_walk_kernel, n_bits=n_bits, ways=ways,
                                n_steps=n_steps, n_luts=len(luts))
-    return pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1, slab_words), lambda b: (b, 0, 0)),
-            *[_full_spec(a) for a in luts],
-            *[row_spec] * 9,
-        ],
-        out_specs=[step_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
-            jax.ShapeDtypeStruct((n_rows, L), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(slabs.reshape(n_blocks, 1, slab_words), *luts, k, y, x0, q0, g_hi,
-      start, stop, keep_lo, keep_hi)
+    with jax.named_scope(WALK_KERNEL):
+        return pl.pallas_call(
+            kernel,
+            grid=(n_blocks,),
+            in_specs=[
+                pl.BlockSpec((1, 1, slab_words), lambda b: (b, 0, 0)),
+                *[_full_spec(a) for a in luts],
+                *[row_spec] * 9,
+            ],
+            out_specs=[step_spec, row_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
+                jax.ShapeDtypeStruct((n_rows, L), jnp.int32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+            name="walk_decode_pallas",
+        )(slabs.reshape(n_blocks, 1, slab_words), *luts, k, y, x0, q0, g_hi,
+          start, stop, keep_lo, keep_hi)

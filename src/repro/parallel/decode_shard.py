@@ -179,7 +179,7 @@ class ShardedExecutor(JnpExecutor):
                       for f in SYMBOL_SPLIT_FIELDS))
             return DecodePlan(key=key, args=args, statics=statics,
                               n_symbols=n_symbols, out_bucket=out_b,
-                              layout=layout)
+                              walk_slots=s_b * W * steps_b, layout=layout)
 
         ds = self.resident(ds)
         # Fused streams built by the microbatcher (device-side concatenate)
@@ -212,7 +212,7 @@ class ShardedExecutor(JnpExecutor):
                 *(jax.device_put(arrs[f], self._rows) for f in SPLIT_FIELDS))
         return DecodePlan(key=key, args=args, statics=statics,
                           n_symbols=n_symbols, out_bucket=out_b,
-                          layout=layout)
+                          walk_slots=s_b * W * steps_b, layout=layout)
 
     def lower(self, plan: DecodePlan):
         st = plan.statics

@@ -13,7 +13,13 @@ One :class:`Observability` object per :class:`~repro.runtime.serve
     counters, capability-registry hit/evict, prethinner speculation,
     controller EMAs, and the broker's per-class deadline-miss accounting;
   * :class:`~repro.runtime.observability.profiler.ExecProfiler` — per
-    -plan-key compile/run timing shared by the decode and encode sessions.
+    -plan-key compile timing shared by the decode and encode sessions.
+
+Beside them, the layer names that reach the JAX profiler: :data:`SCOPES`
+name the stages of the fused decode executable (``jax.named_scope``, so
+they land in each device op's HLO ``op_name`` metadata, the trace's
+``tf_op``), and :data:`SPANS` the host regions around it
+(``jax.profiler.TraceAnnotation``, on the device trace's clock).
 
 ``SCHEMA`` enumerates every metric name the stack can emit with its type
 and label keys.  The schema test pins ``registry.schema()`` against it, so
@@ -33,8 +39,27 @@ from .trace import NULL_TRACE, NullTrace, TicketTracer, Trace
 
 __all__ = [
     "ExecProfiler", "MetricsRegistry", "NULL_TRACE", "NullTrace",
-    "Observability", "SCHEMA", "TicketTracer", "Trace", "waterfall",
+    "Observability", "SCHEMA", "SCOPES", "SPANS", "TicketTracer", "Trace",
+    "waterfall",
 ]
+
+
+# Device scopes of the fused decode executable's three stages: the
+# walk-order gather of the stream words
+# (``rans_decode._walk_order_words``), the Pallas walk kernel's
+# ``pallas_call``, and the scatter of its tiles into the flat output
+# (``ops.scatter_outputs``).  A scope changes HLO metadata only, never the
+# compiled code.
+WALK_GATHER, WALK_KERNEL, SCATTER = SCOPES = (
+    "recoil.walk_gather", "recoil.walk_kernel", "recoil.scatter")
+
+# Host spans: thinning a content's split metadata to a capability (a
+# ``_thinned_batch`` memo miss), building a group's plan and slabs (a
+# ``_group_plan`` memo miss), slicing and fulfilling a group's answers,
+# and the encoder run of an ingest or extend.  They name work, never a
+# wait on the device.
+THIN, PLAN, DELIVER, INGEST = SPANS = (
+    "recoil.thin", "recoil.plan", "recoil.deliver", "recoil.ingest")
 
 
 # Every metric name the stack can emit: name -> (type, label keys).  The
@@ -64,11 +89,11 @@ SCHEMA = {
     "recoil_engine_stream_upload_bytes_total": ("counter", ()),
     "recoil_engine_host_materialized_bytes_total": ("counter", ()),
     "recoil_engine_policy_info": ("gauge", ("impl", "layout", "policy")),
+    "recoil_engine_walk_slots_total": ("counter", ()),
+    "recoil_engine_walk_symbols_total": ("counter", ()),
     # Per-plan-key profiler rollups
     "recoil_profiler_compiles_total": ("counter", ("session",)),
     "recoil_profiler_compile_seconds_total": ("counter", ("session",)),
-    "recoil_profiler_runs_total": ("counter", ("session",)),
-    "recoil_profiler_run_seconds_total": ("counter", ("session",)),
     # Tracer lifecycle
     "recoil_traces_started_total": ("counter", ()),
     "recoil_traces_finished_total": ("counter", ("status",)),
@@ -214,6 +239,7 @@ def _service_samples(svc) -> list[dict]:
 def _engine_samples(svc) -> list[dict]:
     sess = svc.session
     ex = sess.executor
+    slots, symbols = sess.walk_totals()
     return [
         _c("recoil_engine_executables", sess.executables),
         _c("recoil_engine_stream_uploads_total",
@@ -225,6 +251,8 @@ def _engine_samples(svc) -> list[dict]:
         _c("recoil_engine_policy_info", 1,
            {"impl": ex.impl, "layout": ex.layout,
             "policy": getattr(ex.policy, "tag", "?")}),
+        _c("recoil_engine_walk_slots_total", slots),
+        _c("recoil_engine_walk_symbols_total", symbols),
     ]
 
 
@@ -239,10 +267,6 @@ def _profiler_samples(obs: Observability) -> list[dict]:
                {"session": session}),
             _c("recoil_profiler_compile_seconds_total",
                round(t["compile_s"], 6), {"session": session}),
-            _c("recoil_profiler_runs_total", t["runs"],
-               {"session": session}),
-            _c("recoil_profiler_run_seconds_total",
-               round(t["run_s"], 6), {"session": session}),
         ]
     return out
 

@@ -1108,7 +1108,10 @@ class PipelineBroker:
     def snapshot(self) -> dict:
         """The pipeline's observable state: queue depths, wait/service
         latency percentiles, overlap ratio, counters (asserted in tests and
-        reported by ``bench_pipeline``)."""
+        reported by ``bench_pipeline``).  ``walk_slots``/``walk_symbols``
+        are the decode session's running totals of walk positions
+        executed (padding included) and symbols answered."""
+        walk_slots, walk_symbols = self.svc.session.walk_totals()
         with self._cv:
             lanes = {lane: len(q) for lane, q in self._lanes.items() if q}
             depth = self._queued
@@ -1153,6 +1156,8 @@ class PipelineBroker:
             "ingest_errors": self.ingest_errors,
             "extend_events": self.extend_events,
             "stream_dispatches": self.stream_dispatches,
+            "walk_slots": walk_slots,
+            "walk_symbols": walk_symbols,
             "worker_restarts": reliability["worker_restarts"],
             "retries": reliability["retries"],
             "quarantine_rejects": reliability["quarantine_rejects"],

@@ -66,7 +66,8 @@ from repro.core.recoil import RecoilPlan, build_split_states, combine_plan
 from repro.core.vectorized import WalkBatch
 from repro.models.model import LM
 from repro.runtime.faultinject import NULL_INJECTOR
-from repro.runtime.observability import NULL_TRACE, Observability
+from repro.runtime.observability import (DELIVER, INGEST, NULL_TRACE, PLAN,
+                                         THIN, Observability)
 
 
 @dataclasses.dataclass
@@ -423,7 +424,8 @@ class DecodeService:
         Returns the registered :class:`RecoilPlan` (e.g. for clients that
         want to know the supported parallelism)."""
         self.faults.fire("service.ingest", name=name)
-        res = self._encode_session().ingest(symbols, n_splits, name=name)
+        with jax.profiler.TraceAnnotation(INGEST):
+            res = self._encode_session().ingest(symbols, n_splits, name=name)
         self.register(name, res.plan, res.stream, res.final_states)
         with self._lock:
             self._ingests += 1
@@ -441,7 +443,8 @@ class DecodeService:
         never ingested through this service (host-registered content has no
         resumable encoder state — fall back to a full :meth:`ingest`)."""
         self.faults.fire("service.extend", name=name)
-        res = self._encode_session().extend(name, delta)
+        with jax.profiler.TraceAnnotation(INGEST):
+            res = self._encode_session().extend(name, delta)
         self.register(name, res.plan, res.stream, res.final_states)
         with self._lock:
             self._extends += 1
@@ -458,8 +461,9 @@ class DecodeService:
         """Ingest many contents through ONE vmapped encode dispatch:
         ``{name: symbols}`` -> ``{name: RecoilPlan}``."""
         names = list(contents)
-        results = self._encode_session().ingest_batch(
-            [contents[n] for n in names], n_splits)
+        with jax.profiler.TraceAnnotation(INGEST):
+            results = self._encode_session().ingest_batch(
+                [contents[n] for n in names], n_splits)
         for n, r in zip(names, results):
             self.register(n, r.plan, r.stream, r.final_states)
             with self._lock:
@@ -496,9 +500,10 @@ class DecodeService:
             return hit
         self._plan_misses += 1
         c = self._contents[name]
-        plan = combine_plan(c.plan, n_threads)
-        batch = WalkBatch.from_splits(
-            build_split_states(plan, c.final_states), plan.ways)
+        with jax.profiler.TraceAnnotation(THIN):
+            plan = combine_plan(c.plan, n_threads)
+            batch = WalkBatch.from_splits(
+                build_split_states(plan, c.final_states), plan.ways)
         self._batches[key] = (batch, plan.n_symbols)
         return self._batches[key]
 
@@ -802,8 +807,9 @@ class DecodeService:
             _, key, batch, n = reqs[0]
             plan = self._plans.get(key)
             if plan is None:
-                plan = self.session.prepare(
-                    batch, self._contents[key[0]].stream, n)
+                with jax.profiler.TraceAnnotation(PLAN):
+                    plan = self.session.prepare(
+                        batch, self._contents[key[0]].stream, n)
                 self._plans[key] = plan
             return plan, None
         if record:
@@ -815,7 +821,8 @@ class DecodeService:
         if hit is None:
             if len(self._fused_plans) >= self.MAX_FUSED_PLANS:
                 self._fused_plans.pop(next(iter(self._fused_plans)))
-            plan, sym_off, total = self._prepare_fused(reqs)
+            with jax.profiler.TraceAnnotation(PLAN):
+                plan, sym_off, total = self._prepare_fused(reqs)
             self._fused_plans[group] = (plan, sym_off, total)
         else:
             plan, sym_off, total = hit
@@ -828,15 +835,16 @@ class DecodeService:
 
         Span marks (DESIGN.md §13): plan resolution closes "dispatch",
         executable completion closes "execute", fulfillment closes
-        "delivery".  On the broker path, honest execute spans come for
-        free: the broker worker ``block_until_ready``s right after
-        dispatch anyway, so syncing here for traced groups only moves
-        that wait inside the span.  The sync path stays fully
-        asynchronous — there the execute span is the host-side dispatch
-        cost and the caller's ``result()`` owns the device wait (blocking
-        a traced sync flush would CHARGE instrumentation for a sync the
-        uninstrumented path never does, which is exactly what the CI
-        overhead guard prices)."""
+        "delivery"; on the profiler's clock, a plan miss is the
+        ``recoil.plan`` span and fulfillment ``recoil.deliver``. On the
+        broker path, honest execute spans come for free: the broker
+        worker ``block_until_ready``s right after dispatch anyway, so
+        syncing here for traced groups only moves that wait inside the
+        span. The sync path stays fully asynchronous — there the execute
+        span is the host-side dispatch cost and the caller's
+        ``result()`` owns the device wait (blocking a traced sync flush
+        would CHARGE instrumentation for a sync the uninstrumented path
+        never does, which is exactly what the CI overhead guard prices)."""
         with self._lock:
             self._flushes += 1
             plan, sym_off = self._group_plan(reqs)
@@ -856,20 +864,22 @@ class DecodeService:
         # (PipelineTicket) — each trace's span-sum then equals ITS
         # measured end-to-end latency exactly.  One shared mark after the
         # loop would charge every ticket the whole group's delivery tail.
-        if sym_off is None:
-            ticket = reqs[0][0]
-            ticket._fulfill(out=out)
-            td = getattr(ticket, "completed_at", None) or time.perf_counter()
-            ticket.trace.phase("delivery", td)
-            ticket.trace.finish("ok", td)
-        else:
-            for (ticket, _, _, n), off in zip(reqs, sym_off):
-                ticket._fulfill(out=out[off:off + n])
-                if ticket.trace.live:
-                    td = getattr(ticket, "completed_at", None) \
-                        or time.perf_counter()
-                    ticket.trace.phase("delivery", td)
-                    ticket.trace.finish("ok", td)
+        with jax.profiler.TraceAnnotation(DELIVER):
+            if sym_off is None:
+                ticket = reqs[0][0]
+                ticket._fulfill(out=out)
+                td = getattr(ticket, "completed_at", None) \
+                    or time.perf_counter()
+                ticket.trace.phase("delivery", td)
+                ticket.trace.finish("ok", td)
+            else:
+                for (ticket, _, _, n), off in zip(reqs, sym_off):
+                    ticket._fulfill(out=out[off:off + n])
+                    if ticket.trace.live:
+                        td = getattr(ticket, "completed_at", None) \
+                            or time.perf_counter()
+                        ticket.trace.phase("delivery", td)
+                        ticket.trace.finish("ok", td)
 
     def _prepare_fused(self, reqs) -> tuple[DecodePlan, list[int], int]:
         streams: dict[int, DeviceStream] = {}
