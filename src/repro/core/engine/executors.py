@@ -285,7 +285,7 @@ class PallasExecutor(Executor):
         from repro.kernels.rans_decode.ops import (build_slabs, pack_batch,
                                                    pad_to_rows)
         from repro.kernels.rans_decode.rans_decode import (
-            LANES, POINTER_KERNEL_REFUSAL, check_vmem)
+            LANES, POINTER_KERNEL_REFUSAL, check_vmem, window_guard)
         layout = self.select_layout(ds)
         if layout == "pointer" and not self.interpret:
             raise NotImplementedError(POINTER_KERNEL_REFUSAL)
@@ -309,16 +309,18 @@ class PallasExecutor(Executor):
             # Per-block slab of the PERMUTATION: rows gather symbol indices
             # in [stop + sym_base, start + sym_base], so reuse the q0-window
             # slab builder with hi = start + sym_base, span = start - stop
-            # (+1 slack below; the builder already clamps at 0).
+            # (+1 slack below; build_slabs already clamps at 0), guarded so
+            # that each split's walk-order window is one in-range slice.
             win = dict(q0=per_split["start"] + per_split["sym_base"],
                        span=per_split["span"])
-            slabs, slab_lo = build_slabs(self._host_by_symbol(ds), win,
-                                         rows, pack, rpb)
+            slabs, origin = build_slabs(self._host_by_symbol(ds), win,
+                                        rows, pack, rpb,
+                                        guard=window_guard(steps_b, W))
             slab_b = self.policy.mem(slabs.shape[1], 8)
             if slab_b > slabs.shape[1]:
                 slabs = np.pad(slabs, ((0, 0), (0, slab_b - slabs.shape[1])))
-            lo_rows = np.repeat(slab_lo, rpb * pack).astype(np.int32)
-            sym_rel = per_split["sym_base"] - lo_rows
+            sym_rel = per_split["sym_base"] - np.repeat(
+                origin, rpb * pack).astype(np.int32)
             sym_rel_packed = np.ascontiguousarray(
                 np.repeat(sym_rel.reshape(-1, pack), W, axis=1))
             key = (self.impl, layout, self.policy.tag, self.packed_lut,

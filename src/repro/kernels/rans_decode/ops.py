@@ -101,29 +101,36 @@ def pad_to_rows(packed: dict, per_split: dict, rows: int, pack: int,
 
 
 def build_slabs(stream: np.ndarray, per_split: dict, rows: int, pack: int,
-                rows_per_block: int):
+                rows_per_block: int, guard: tuple[int, int] = (0, 0)):
     """Per-block stream slabs.  A split consumes at most one word per walked
     symbol index, so its reads live in ``[q0 - span, q0]``; the block slab is
     the union over its splits, padded to the max block width (multiple of 8
-    words for sublane alignment)."""
+    words for sublane alignment).  ``guard = (front, back)`` puts that many
+    zero words before and after every block's window.
+
+    Returns ``(slabs, origin)``: column ``j`` of block ``b`` holds
+    ``stream[origin[b] + j]``."""
     n_blocks = rows // rows_per_block
     per_block = rows_per_block * pack
     q0 = per_split["q0"].reshape(n_blocks, per_block)
     span = per_split["span"].reshape(n_blocks, per_block)
     lo = np.maximum(0, (q0 - span).min(axis=1))
     hi = q0.max(axis=1)
-    width = int((hi - lo + 1).max())
+    front, back = guard
+    width = front + int((hi - lo + 1).max()) + back
     width = -(-width // 8) * 8
+    origin = lo - front
     stream32 = np.ascontiguousarray(stream).astype(np.uint32).astype(np.int32)
     n = len(stream32)
     if n == 0:
-        return np.zeros((n_blocks, width), dtype=np.int32), lo
+        return np.zeros((n_blocks, width), dtype=np.int32), origin
     # One strided gather builds every slab: block b's row reads
-    # stream[lo[b] + j] for j < hi[b]-lo[b]+1, zero elsewhere.
-    idx = lo[:, None] + np.arange(width, dtype=np.int64)[None, :]
-    valid = idx <= hi[:, None]
-    slabs = np.where(valid, stream32[np.minimum(idx, n - 1)], 0)
-    return np.ascontiguousarray(slabs.astype(np.int32)), lo
+    # stream[origin[b] + j] where that lies in [lo[b], hi[b]], zero
+    # elsewhere.
+    idx = origin[:, None] + np.arange(width, dtype=np.int64)[None, :]
+    valid = (idx >= lo[:, None]) & (idx <= hi[:, None])
+    slabs = np.where(valid, stream32[np.clip(idx, 0, n - 1)], 0)
+    return np.ascontiguousarray(slabs.astype(np.int32)), origin
 
 
 def packed_lut_ok(model: StaticModel) -> bool:

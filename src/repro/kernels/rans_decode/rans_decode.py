@@ -26,10 +26,13 @@ Stream residency: each grid block's stream window is a per-block *slab*
 its splits, so the kernel never needs the full bitstream.
 
   * Symbol layout (:func:`_walk_kernel_symbol`): a lane's word is a
-    closed-form function of its own walk index, so the wrapper gathers every
-    step's words into walk order with one XLA gather before the kernel, and
-    the kernel reads ``words_ref[t]`` — no gather from the stream in the
-    kernel.  This is the layout every ingested content serves under.
+    closed-form function of its own walk index, and over the walk a split's
+    W lanes read one contiguous window of its block's slab.  So the wrapper
+    reads each split's window as one slice (:func:`_walk_order_words`; the
+    slab carries :func:`window_guard` zeros so that no slice start is ever
+    clamped), lays the windows out in walk order, and the kernel reads
+    ``words_ref[t]`` — no gather from the stream in the kernel.  This is
+    the layout every ingested content serves under.
   * Pointer layout (:func:`_walk_kernel`): the word index depends on the
     walk state (``q`` minus the read offset), so the kernel gathers it from
     the block's slab every step.  Mosaic refuses that 1-D gather and the
@@ -283,20 +286,56 @@ def _walk_kernel_symbol(words_ref, *refs, n_bits: int, ways: int,
     jax.lax.fori_loop(0, n_steps, step, x0_ref[...].astype(jnp.uint32))
 
 
+def window_guard(n_steps: int, ways: int) -> tuple[int, int]:
+    """Zero words a symbol-layout slab carries before and after its window
+    (``ops.build_slabs``'s ``guard``), so that every split's walk-order
+    window (:func:`_walk_order_words`) lies inside the slab."""
+    return n_steps * ways, ways
+
+
 def _walk_order_words(slabs: jax.Array, sym_rel: jax.Array, g_hi: jax.Array,
                       *, ways: int, n_steps: int,
                       rows_per_block: int) -> jax.Array:
     """(T, rows, 128) symbol-layout words in walk order: at step ``t`` lane
     ``l`` of row ``r`` reads ``slab[i + sym_rel]`` of its block, where
-    ``i = (g_hi - t) * ways + l % ways`` is its walk index."""
+    ``i = (g_hi - t) * ways + l % ways`` is its walk index.
+
+    ``g_hi`` and ``sym_rel`` are per split (broadcast over its W lanes), so
+    at step ``t`` a split's lanes read W consecutive words, and each step
+    moves them down by W: over the walk a split reads the one window
+    ``slab[sym_rel + (g_hi - T + 1) * W : sym_rel + (g_hi + 1) * W]``, its
+    last W-word row first.  So the words are one contiguous ``T * W``-word
+    slice per split, relaid to the kernel's tiles by one transpose and a
+    reversal of the steps.
+
+    A slice must never move: a gather clamps an out-of-range start, which
+    would shift every word of the split.  Every window lies inside a slab
+    built with :func:`window_guard`'s ``T * W`` zero words in front and W
+    behind: ``ops.build_slabs`` puts the words of a split's indices
+    ``[stop, start]`` between the guards, so ``stop + sym_rel >= T * W`` and
+    ``start + sym_rel < width - W``; with ``(g_hi + 1) * W >= stop``
+    (``start >= stop - 1``) and ``g_hi * W <= start`` the window starts at
+    or after 0 and ends at or before ``width``.  Inert padding splits have
+    ``g_hi = 0`` and ``sym_rel = T * W``.
+    """
     with jax.named_scope(WALK_GATHER):
         n_rows, L = g_hi.shape
-        t = jnp.arange(n_steps, dtype=jnp.int32)[:, None, None]
-        lane = (jnp.arange(L, dtype=jnp.int32) % ways)[None, None, :]
-        idx = jnp.clip((g_hi[None] - t) * ways + lane + sym_rel[None], 0,
-                       slabs.shape[1] - 1)
-        block = (jnp.arange(n_rows, dtype=jnp.int32) // rows_per_block)
-        return slabs[block[None, :, None], idx]
+        T, W = n_steps, ways
+        pack = L // W
+        # One value per split: the first lane of its W-lane segment.
+        top = g_hi.reshape(n_rows * pack, W)[:, 0]
+        rel = sym_rel.reshape(n_rows * pack, W)[:, 0]
+        block = jnp.arange(n_rows * pack, dtype=jnp.int32) // (
+            rows_per_block * pack)
+        starts = jnp.stack([block, rel + (top - T + 1) * W], axis=1)
+        windows = jax.lax.gather(
+            slabs, starts,
+            jax.lax.GatherDimensionNumbers(offset_dims=(1,),
+                                           collapsed_slice_dims=(0,),
+                                           start_index_map=(0, 1)),
+            slice_sizes=(1, T * W), mode="clip")
+        words = windows.reshape(n_rows, pack, T, W).transpose(2, 0, 1, 3)
+        return jax.lax.rev(words.reshape(T, n_rows, L), (0,))
 
 
 def _lut_args(sym_lut, f_lut, F_lut) -> tuple:
@@ -323,9 +362,10 @@ def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
                               n_steps: int, rows_per_block: int,
                               interpret: bool):
     """pallas_call wrapper for the symbol-indexed walk.  ``slabs`` is the
-    per-block window of ``words_by_symbol`` with ``sym_rel`` already
-    slab-relative; everything else matches :func:`walk_decode_pallas`
-    minus the stream pointer (no ``q0``, no ``qf`` output).
+    per-block window of ``words_by_symbol`` between :func:`window_guard`
+    zeros, with ``sym_rel`` already slab-relative; everything else matches
+    :func:`walk_decode_pallas` minus the stream pointer (no ``q0``, no
+    ``qf`` output).
 
     Returns int32 (n_steps, n_rows, 128), -1 where not kept.
     """

@@ -7,6 +7,7 @@ tests/test_tpu_compile.py compiles them for a described v5e).
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from numpy.testing import assert_allclose
@@ -184,3 +185,88 @@ def test_pallas_plan_refuses_walk_over_vmem_limit():
                             plan.n_symbols)
     with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
         sess.prepare(batch, ds, plan.n_symbols)
+
+
+# ----------------------------------------------------------------------
+# Walk-order words: one window read per split
+# ----------------------------------------------------------------------
+
+def _elementwise_walk_order_words(slabs, sym_rel, g_hi, *, ways, n_steps,
+                                  rows_per_block):
+    """The reference: one clamped slab element per walk slot.  Returns the
+    (T, rows, 128) words and the mask of slots whose index was in range."""
+    n_rows, L = g_hi.shape
+    t = np.arange(n_steps)[:, None, None]
+    lane = (np.arange(L) % ways)[None, None, :]
+    raw = (g_hi[None] - t) * ways + lane + sym_rel[None]
+    block = np.arange(n_rows)[None, :, None] // rows_per_block
+    words = slabs[block, np.clip(raw, 0, slabs.shape[1] - 1)]
+    return words, (raw >= 0) & (raw < slabs.shape[1])
+
+
+@pytest.mark.parametrize("ways", [32, 128])
+@pytest.mark.parametrize("layout", ["from_zero", "deep", "inert_padding"])
+def test_walk_order_windows_match_elementwise_gather(ways, layout):
+    """The window read gives the element-wise gather's word at every slot
+    whose index is in the unguarded slab, every active slot among them.
+
+    Slabs are built as ``PallasExecutor.plan`` builds them.  ``from_zero``
+    starts the walk at index 0 (splits shorter than the walk, windows
+    reaching below the slab's start); ``deep`` starts far into a
+    permutation, so ``sym_rel`` is large, negative and not a multiple of
+    W; ``inert_padding`` leaves a partial row and a partial block of inert
+    splits.  Every block's last split ends at its slab's last word."""
+    from repro.kernels.rans_decode.ops import (build_slabs, pack_batch,
+                                               pad_to_rows)
+    from repro.kernels.rans_decode.rans_decode import (LANES,
+                                                       _walk_order_words,
+                                                       window_guard)
+    rng = np.random.default_rng([ways, len(layout)])
+    T, R = 12, 8
+    pack = LANES // ways
+    S = 3 * R * pack - (R * pack // 2 + 1 if layout == "inert_padding" else 0)
+    lengths = rng.integers(1, (T - 2) * ways, S)
+    lengths[: S // 4] = rng.integers(1, ways, S // 4)     # one-group splits
+    begin = 0 if layout == "from_zero" else int(rng.integers(10**5, 10**6))
+    stop = begin + np.concatenate([[0], np.cumsum(lengths)[:-1]])
+    start = stop + lengths - 1
+    sym_base = ways * int(rng.integers(0, 100))
+    stream = rng.integers(-2**31, 2**31, int(start[-1]) + sym_base + 1,
+                          dtype=np.int64).astype(np.int32)
+    z = np.zeros((S, ways), np.int32)
+    zs = np.zeros(S, np.int32)
+    batch = WalkBatch(k=z, y=z.view(np.uint32), x0=z.view(np.uint32), q0=zs,
+                      g_hi=(start // ways).astype(np.int32),
+                      start=start.astype(np.int32),
+                      stop=stop.astype(np.int32), keep_lo=zs, keep_hi=zs,
+                      out_base=zs, n_steps=T, ways=ways,
+                      sym_base=np.full(S, sym_base, np.int32))
+    packed, per_split, rows, _, _ = pack_batch(batch)
+    rows = pad_to_rows(packed, per_split, rows, pack, -(-rows // R) * R)
+    win = dict(q0=per_split["start"] + per_split["sym_base"],
+               span=per_split["span"])
+
+    def rel_lanes(origin):
+        rel = per_split["sym_base"] - np.repeat(origin, R * pack)
+        return np.repeat(rel.reshape(rows, pack), ways, axis=1)
+
+    plain, lo = build_slabs(stream, win, rows, pack, R)
+    guarded, origin = build_slabs(stream, win, rows, pack, R,
+                                  guard=window_guard(T, ways))
+    g_hi = packed["g_hi"]
+    ref, in_range = _elementwise_walk_order_words(
+        plain, rel_lanes(lo), g_hi, ways=ways, n_steps=T, rows_per_block=R)
+    words = np.asarray(_walk_order_words(
+        jnp.asarray(guarded), jnp.asarray(rel_lanes(origin)),
+        jnp.asarray(g_hi), ways=ways, n_steps=T, rows_per_block=R))
+
+    t = np.arange(T)[:, None, None]
+    i = (g_hi[None] - t) * ways + (np.arange(LANES) % ways)[None, None, :]
+    active = (i >= packed["stop"][None]) & (i <= packed["start"][None])
+    assert active.sum() == lengths.sum()
+    assert in_range[active].all()
+    assert_allclose(words[in_range], ref[in_range], rtol=0, atol=0)
+    if layout == "from_zero":
+        assert not in_range.all()     # some windows start below the slab
+    if layout == "deep":
+        assert (rel_lanes(lo) % ways).any()

@@ -13,6 +13,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from repro.core.vectorized import _walk_batch_symbol_jit
 from repro.kernels.rans_decode import ops
 from repro.kernels.rans_decode.rans_decode import (LANES,
                                                    POINTER_KERNEL_REFUSAL)
+from repro.runtime.observability import WALK_GATHER
 
 N_SYMBOLS = 10_000_000     # benchmarks.datasets.rand_exponential(50)
 WAYS = 32
@@ -87,13 +89,41 @@ def _pallas_statics(sh: dict) -> dict:
                 n_symbols=sh["out"])
 
 
+@pytest.fixture(scope="module")
+def fused_symbol_decode(one_chip):
+    """The fused symbol-layout decode compiled for the v5e, once per
+    capability for every test of this file."""
+    compiled = {}
+
+    def compile_for(capability: int):
+        if capability not in compiled:
+            sh = _shapes(capability)
+            compiled[capability] = ops.decode_tiles_fused_symbol.lower(
+                *_pallas_args(one_chip, sh), **_pallas_statics(sh)).compile()
+        return compiled[capability]
+    return compile_for
+
+
 @pytest.mark.parametrize("capability", [2048, 256])
-def test_fused_symbol_decode_compiles_for_v5e(one_chip, capability):
-    sh = _shapes(capability)
-    compiled = ops.decode_tiles_fused_symbol.lower(
-        *_pallas_args(one_chip, sh), **_pallas_statics(sh)).compile()
+def test_fused_symbol_decode_compiles_for_v5e(fused_symbol_decode,
+                                              capability):
+    compiled = fused_symbol_decode(capability)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("capability", [2048, 256])
+def test_walk_gather_reads_windows_not_elements(fused_symbol_decode,
+                                                capability):
+    """The walk-order words are read as one window per split: no gather of
+    the compiled ``recoil.walk_gather`` scope takes single elements."""
+    hlo = fused_symbol_decode(capability).as_text()
+    gathers = [line for line in hlo.splitlines()
+               if re.search(r"\sgather\(", line) and WALK_GATHER in line]
+    elementwise = [g for g in gathers
+                   if set(re.search(r"slice_sizes=\{([\d,]*)\}", g)
+                          .group(1).split(",")) == {"1"}]
+    assert not elementwise, elementwise[0]
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
