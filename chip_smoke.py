@@ -8,8 +8,9 @@ asset (``benchmarks.datasets.rand_exponential(50)``, seeded), ingests it on
 the device at 2048 splits (W = 32, n = 11, packed LUT) and decodes it through
 ``DecodeService`` and its ``PipelineBroker`` at several client capabilities:
 
-  * one chip: capabilities 2048 and 256 on ``impl="pallas"`` (the Mosaic
-    -compiled walk kernel) and 2048 and 64 on ``impl="jnp"`` (the XLA walk);
+  * one chip: capabilities 2048, 256 and 16 on ``impl="pallas"`` (the
+    Mosaic-compiled walk kernel; at 16 splits it walks 24,576 steps in T
+    tiles) and 2048 and 64 on ``impl="jnp"`` (the XLA walk);
   * ``--four-chips``: capabilities 2048 and 64 on ``impl="sharded"`` over a
     four-device mesh, against the one-chip XLA walk on device 0.
 
@@ -39,7 +40,7 @@ import jax  # noqa: E402
 
 ASSET = "rand_50"
 N_SPLITS = 2048
-PALLAS_CAPS = (2048, 256)
+PALLAS_CAPS = (2048, 256, 16)
 JNP_CAPS = (2048, 64)
 SHARDED_CAPS = (2048, 64)
 REPEATS = 2            # broker submits per capability

@@ -83,14 +83,28 @@ def pack_single(enc: EncodedStream, model: StaticModel) -> bytes:
             + enc.stream.astype("<u2").tobytes())
 
 
-def pack_recoil(enc: EncodedStream, model: StaticModel, plan: RecoilPlan) -> bytes:
+def _recoil_head(model: StaticModel, plan: RecoilPlan, n_symbols: int,
+                 n_words: int, final_states: np.ndarray) -> bytes:
+    """A RECOIL container up to its stream words."""
     head = MAGIC + struct.pack("<BBHQQ", KIND_RECOIL, model.params.n_bits,
-                               model.params.ways, enc.n_symbols, enc.n_words)
+                               model.params.ways, n_symbols, n_words)
     blob = md.serialize_plan(plan)
-    return (head + _pack_table(model)
-            + enc.final_states.astype("<u4").tobytes()
-            + struct.pack("<I", len(blob)) + blob
+    return (head + _pack_table(model) + final_states.astype("<u4").tobytes()
+            + struct.pack("<I", len(blob)) + blob)
+
+
+def pack_recoil(enc: EncodedStream, model: StaticModel, plan: RecoilPlan) -> bytes:
+    return (_recoil_head(model, plan, enc.n_symbols, enc.n_words,
+                         enc.final_states)
             + enc.stream.astype("<u2").tobytes())
+
+
+def recoil_size(model: StaticModel, plan: RecoilPlan, n_words: int,
+                final_states: np.ndarray) -> int:
+    """``len(pack_recoil(enc, model, plan))`` for a stream of ``n_words``
+    words, without the stream: no stream word is read."""
+    return len(_recoil_head(model, plan, plan.n_symbols, n_words,
+                            final_states)) + 2 * n_words
 
 
 def pack_recoil_chunked(enc: EncodedStream, model: StaticModel,

@@ -294,9 +294,6 @@ class PallasExecutor(Executor):
         W = batch.ways
         rpb = self.rows_per_block
         steps_b = self.policy.work(batch.n_steps)
-        if layout == "symbol":
-            check_vmem(steps_b, rpb, sum(-(-l.shape[0] // LANES) * LANES
-                                         for l in self.luts if l is not None))
         packed, per_split, rows, pack, _ = pack_batch(batch)
         rows = pad_to_rows(packed, per_split, rows, pack,
                            self.policy.work(-(-rows // rpb)) * rpb)
@@ -305,6 +302,11 @@ class PallasExecutor(Executor):
                        rows_per_block=rpb, interpret=self.interpret,
                        pack=pack, n_symbols=out_b)
         if layout == "symbol":
+            # The walk's T tile, from the shapes and the VMEM limit.
+            tile = check_vmem(steps_b, rpb, sum(
+                -(-l.shape[0] // LANES) * LANES
+                for l in self.luts if l is not None))
+            statics["tile_steps"] = tile
             _check_sym_alignment(batch, ds, W)
             # Per-block slab of the PERMUTATION: rows gather symbol indices
             # in [stop + sym_base, start + sym_base], so reuse the q0-window
@@ -324,7 +326,7 @@ class PallasExecutor(Executor):
             sym_rel_packed = np.ascontiguousarray(
                 np.repeat(sym_rel.reshape(-1, pack), W, axis=1))
             key = (self.impl, layout, self.policy.tag, self.packed_lut,
-                   p.n_bits, W, rows, steps_b, slab_b, out_b, rpb)
+                   p.n_bits, W, rows, steps_b, slab_b, out_b, rpb, tile)
             args = (jnp.asarray(slabs), *self.luts,
                     jnp.asarray(packed["k"]), jnp.asarray(packed["y"]),
                     jnp.asarray(packed["x0"]), jnp.asarray(sym_rel_packed),
@@ -337,6 +339,7 @@ class PallasExecutor(Executor):
             return DecodePlan(key=key, args=args, statics=statics,
                               n_symbols=n_symbols, out_bucket=out_b,
                               walk_slots=steps_b * rows * LANES,
+                              walk_tiles=steps_b // tile * rows // rpb,
                               layout=layout)
         host_words = self._host_words(ds)
         slabs, slab_lo = build_slabs(host_words, per_split, rows, pack, rpb)
@@ -357,7 +360,8 @@ class PallasExecutor(Executor):
                 jnp.asarray(per_split["out_base"]))
         return DecodePlan(key=key, args=args, statics=statics,
                           n_symbols=n_symbols, out_bucket=out_b,
-                          walk_slots=steps_b * rows * LANES, layout=layout)
+                          walk_slots=steps_b * rows * LANES,
+                          walk_tiles=rows // rpb, layout=layout)
 
     def lower(self, plan: DecodePlan):
         from repro.kernels.rans_decode.ops import (decode_tiles_fused,

@@ -21,7 +21,9 @@ A :class:`DecodePlan` captures everything the executable call needs:
                    after the call;
   * ``walk_slots``— the lane positions the walk executes: bucketed steps
                    times every lane of the executor's tile, padding
-                   included (``n_symbols`` of them answer symbols).
+                   included (``n_symbols`` of them answer symbols);
+  * ``walk_tiles``— the walk kernel's grid steps: T tiles times row
+                   blocks (0 for the XLA walks, which have no grid).
 
 Bucketing policy (DESIGN.md §4): memory-dominant dims pad to powers of two
 (:func:`pow2_bucket`), compute-dominant dims to powers of two and their
@@ -228,6 +230,7 @@ class DecodePlan:
     n_symbols: int
     out_bucket: int
     walk_slots: int
+    walk_tiles: int = 0
     layout: str = "pointer"   # plan-IR layout axis (see LAYOUTS)
 
 

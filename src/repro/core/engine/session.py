@@ -47,6 +47,7 @@ class EngineStats:
     decodes: int = 0
     walk_slots: int = 0    # walk positions executed, padding included
     walk_symbols: int = 0  # symbols those walks answered
+    walk_tiles: int = 0    # walk-kernel grid steps: T tiles x row blocks
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -176,12 +177,14 @@ class DecoderSession:
             exes = list(self._exec.values())
         return [exe.as_text() for exe in exes]
 
-    def walk_totals(self) -> tuple[int, int]:
-        """``(walk_slots, walk_symbols)``, read together: the walk
-        positions every executed plan ran (bucketed steps over all its
-        lanes, padding included) and the symbols they answered."""
+    def walk_totals(self) -> tuple[int, int, int]:
+        """``(walk_slots, walk_symbols, walk_tiles)``, read together: the
+        walk positions every executed plan ran (bucketed steps over all
+        its lanes, padding included), the symbols they answered, and the
+        walk kernel's grid steps (T tiles x row blocks)."""
         with self._lock:
-            return self.stats.walk_slots, self.stats.walk_symbols
+            return (self.stats.walk_slots, self.stats.walk_symbols,
+                    self.stats.walk_tiles)
 
     def execute(self, plan: DecodePlan) -> jax.Array:
         """Run a prepared plan: compile on bucket miss, else reuse.
@@ -193,6 +196,7 @@ class DecoderSession:
             self.stats.decodes += 1
             self.stats.walk_slots += plan.walk_slots
             self.stats.walk_symbols += plan.n_symbols
+            self.stats.walk_tiles += plan.walk_tiles
             exe = self._exec.get(plan.key)
             if exe is None:
                 if prof is None:

@@ -246,22 +246,24 @@ def decode_tiles_fused(slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi,
 
 @functools.partial(jax.jit, static_argnames=(
     "n_bits", "ways", "n_steps", "rows_per_block", "interpret", "pack",
-    "n_symbols"))
+    "n_symbols", "tile_steps"))
 def decode_tiles_fused_symbol(slabs, sym_lut, f_lut, F_lut, k, y, x0, sym_rel,
                               g_hi, start, stop, keep_lo, keep_hi, g_hi_split,
                               out_base_split, *, n_bits: int, ways: int,
                               n_steps: int, rows_per_block: int,
-                              interpret: bool, pack: int,
-                              n_symbols: int) -> jax.Array:
+                              interpret: bool, pack: int, n_symbols: int,
+                              tile_steps: int) -> jax.Array:
     """Symbol-layout twin of :func:`decode_tiles_fused`: the pointer-free
     Pallas walk (``slabs`` hold per-block ``words_by_symbol`` windows,
-    ``sym_rel`` the slab-relative permutation bases) + the same on-device
-    scatter, fused into ONE cacheable executable."""
+    ``sym_rel`` the slab-relative permutation bases, ``tile_steps`` its T
+    tile) + the same on-device scatter, fused into ONE cacheable
+    executable.  The scatter reads the whole ``(T, rows, 128)`` tile."""
     from .rans_decode import walk_decode_symbol_pallas
     out = walk_decode_symbol_pallas(
         slabs, sym_lut, f_lut, F_lut, k, y, x0, sym_rel, g_hi, start, stop,
         keep_lo, keep_hi, n_bits=n_bits, ways=ways, n_steps=n_steps,
-        rows_per_block=rows_per_block, interpret=interpret)
+        rows_per_block=rows_per_block, interpret=interpret,
+        tile_steps=tile_steps)
     return scatter_outputs(out, g_hi_split, out_base_split, ways=ways,
                            pack=pack, n_symbols=n_symbols)
 

@@ -18,7 +18,7 @@ On TPU the natural unit is the (8, 128) VPU vector tile, so we:
     ``slot % 128`` and selects row ``slot // 128`` (16 lane gathers for the
     packed n = 11 table).
 
-Step layout: the output block is ``(T, ROWS, 128)`` with the walk step on
+Step layout: the output block is ``(Tt, ROWS, 128)`` with the walk step on
 the leading axis, so step ``t`` writes ``out_ref[t]`` — a whole-tile store.
 
 Stream residency: each grid block's stream window is a per-block *slab*
@@ -40,10 +40,19 @@ its splits, so the kernel never needs the full bitstream.
     so this kernel runs only interpreted, on CPU; ``PallasExecutor.plan``
     refuses pointer content on a TPU.
 
-VMEM: every block is double-buffered and the output and word blocks span
-the whole walk, so a symbol-layout block needs about ``4 * T * ROWS * 128 *
-4`` bytes.  ``PallasExecutor.plan`` refuses a walk over
-:data:`VMEM_LIMIT_BYTES` (:func:`check_vmem`, ROADMAP S5/R3).
+T tiles (symbol layout): the grid is ``(row blocks, T tiles)``.  A grid
+step walks ``Tt`` consecutive steps of one row block; its word and output
+blocks are ``(Tt, ROWS, 128)``, so a block needs about ``4 * Tt * ROWS *
+128 * 4`` bytes of VMEM (every block is double-buffered) whatever the
+walk's length.  The lane states live in a VMEM scratch tile: the first
+tile of a row block loads them from ``x0``, each tile walks global steps
+``j * Tt + t`` and leaves them for the next (the tile axis is
+``"arbitrary"``, so its steps run in order).  :func:`check_vmem` derives
+``Tt`` from the shapes: the whole walk when its block fits
+:data:`VMEM_LIMIT_BYTES` (one tile, the 2048- and 256-split clients of a
+10 MB asset), else the largest power of two dividing ``T`` whose block
+fits (a 16-split client's 24,576 steps walk in six tiles of 4,096).  The
+pointer kernel keeps whole-walk blocks.
 
 Walk-step recurrences are exactly :func:`repro.core.vectorized._walk_one_split`
 (the jnp oracle these kernels are tested against, see ref.py):
@@ -98,19 +107,34 @@ def interpret_mode() -> bool:
         f"(interpreted); the default backend is {backend!r}")
 
 
-def check_vmem(n_steps: int, rows_per_block: int, lut_words: int) -> None:
-    """Raise when a symbol-layout walk's grid block does not fit
-    :data:`VMEM_LIMIT_BYTES`.  Every block is double-buffered: the
-    (T, ROWS, 128) words and output, the slot tables and the nine
-    (ROWS, 128) per-lane tiles."""
+def block_vmem_bytes(tile_steps: int, rows_per_block: int,
+                     lut_words: int) -> int:
+    """VMEM one symbol-layout grid step takes, every block double-buffered:
+    the (Tt, ROWS, 128) words and output, the slot tables and the eight
+    (ROWS, 128) per-lane tiles, plus the lane-state scratch tile."""
     tile = rows_per_block * LANES * 4
-    need = 2 * (2 * n_steps * tile + 4 * lut_words + 9 * tile)
+    return 2 * (2 * tile_steps * tile + 4 * lut_words + 8 * tile) + tile
+
+
+def check_vmem(n_steps: int, rows_per_block: int, lut_words: int) -> int:
+    """The symbol-layout walk's T tile: ``n_steps`` when the whole walk's
+    block fits :data:`VMEM_LIMIT_BYTES`, else the largest power of two
+    dividing ``n_steps`` whose block fits.  Raises only when no tile fits,
+    i.e. when the slot tables alone fill the limit."""
+    if block_vmem_bytes(n_steps, rows_per_block, lut_words) \
+            <= VMEM_LIMIT_BYTES:
+        return n_steps
+    tile = n_steps & -n_steps          # largest power of two dividing T
+    while tile > 1 and block_vmem_bytes(tile, rows_per_block,
+                                        lut_words) > VMEM_LIMIT_BYTES:
+        tile //= 2
+    need = block_vmem_bytes(tile, rows_per_block, lut_words)
     if need > VMEM_LIMIT_BYTES:
         raise ValueError(
-            f"a Pallas walk of {n_steps} steps x {rows_per_block} rows needs "
-            f"{need} B of VMEM per block, over the kernel's "
-            f"VMEM_LIMIT_BYTES = {VMEM_LIMIT_BYTES} B (the output block "
-            f"spans the whole walk); decode this capability with impl='jnp'")
+            f"a Pallas walk step of {rows_per_block} rows with {lut_words} "
+            f"slot-table words needs {need} B of VMEM, over the kernel's "
+            f"VMEM_LIMIT_BYTES = {VMEM_LIMIT_BYTES} B")
+    return tile
 
 
 def lut_tiles(lut: jax.Array) -> jax.Array:
@@ -240,22 +264,30 @@ def _walk_kernel(stream_ref, *refs, n_bits: int, ways: int, n_steps: int,
 
 
 def _walk_kernel_symbol(words_ref, *refs, n_bits: int, ways: int,
-                        n_steps: int, n_luts: int):
-    """Pointer-free grid step (symbol-indexed layout, DESIGN.md §9).
+                        tile_steps: int, n_luts: int):
+    """Pointer-free grid step (symbol-indexed layout, DESIGN.md §9): walk
+    global steps ``j * Tt .. j * Tt + Tt - 1`` of one row block, ``j`` the
+    grid's T-tile index.
 
-    ``words_ref[t]`` holds the (ROWS, 128) words the lanes read at step
-    ``t`` (gathered into walk order by :func:`_walk_order_words`), so the
-    warp-ballot/cumsum read-offset machinery of :func:`_walk_kernel` and
-    every stream gather disappear and the carry is just the lane states.
+    ``words_ref[t]`` holds the (ROWS, 128) words the lanes read at the
+    tile's step ``t`` (gathered into walk order by
+    :func:`_walk_order_words`), so the warp-ballot/cumsum read-offset
+    machinery of :func:`_walk_kernel` and every stream gather disappear and
+    the carry is just the lane states, kept in ``x_ref`` between tiles.
     """
     lut_refs = refs[:n_luts]
     (k_ref, y_ref, x0_ref, ghi_ref, start_ref, stop_ref, klo_ref, khi_ref,
-     out_ref) = refs[n_luts:]
+     out_ref, x_ref) = refs[n_luts:]
     L_bound = jnp.uint32(1 << 16)
     b_bits = jnp.uint32(16)
     slot_mask = jnp.uint32((1 << n_bits) - 1)
     rows, L = k_ref.shape
     lane_in_seg = jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1) & (ways - 1)
+    tile = pl.program_id(1)
+
+    @pl.when(tile == 0)
+    def _():
+        x_ref[...] = x0_ref[...]
 
     k = k_ref[...]
     y = y_ref[...].astype(jnp.uint32)
@@ -263,10 +295,10 @@ def _walk_kernel_symbol(words_ref, *refs, n_bits: int, ways: int,
     stop = stop_ref[...]
     keep_lo = klo_ref[...]
     keep_hi = khi_ref[...]
-    g_hi = ghi_ref[...]
+    g_top = ghi_ref[...] - tile * tile_steps
 
     def step(t, x):
-        g = g_hi - t
+        g = g_top - t
         i = g * ways + lane_in_seg
         active = (i <= start) & (i >= stop)
         recon = active & (i == k)
@@ -283,7 +315,9 @@ def _walk_kernel_symbol(words_ref, *refs, n_bits: int, ways: int,
         out_ref[t] = jnp.where(keep, s, -1)
         return x_new
 
-    jax.lax.fori_loop(0, n_steps, step, x0_ref[...].astype(jnp.uint32))
+    x = jax.lax.fori_loop(0, tile_steps, step,
+                          x_ref[...].astype(jnp.uint32))
+    x_ref[...] = x.astype(jnp.int32)
 
 
 def window_guard(n_steps: int, ways: int) -> tuple[int, int]:
@@ -346,12 +380,13 @@ def _lut_args(sym_lut, f_lut, F_lut) -> tuple:
 
 
 def _full_spec(arr) -> pl.BlockSpec:
-    return pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
+    return pl.BlockSpec(arr.shape, lambda *_: (0,) * arr.ndim)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_bits", "ways", "n_steps", "rows_per_block", "interpret"))
+    static_argnames=("n_bits", "ways", "n_steps", "rows_per_block",
+                     "interpret", "tile_steps"))
 def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
                               f_lut: jax.Array | None,
                               F_lut: jax.Array | None, k: jax.Array,
@@ -360,12 +395,13 @@ def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
                               stop: jax.Array, keep_lo: jax.Array,
                               keep_hi: jax.Array, *, n_bits: int, ways: int,
                               n_steps: int, rows_per_block: int,
-                              interpret: bool):
+                              interpret: bool, tile_steps: int):
     """pallas_call wrapper for the symbol-indexed walk.  ``slabs`` is the
     per-block window of ``words_by_symbol`` between :func:`window_guard`
     zeros, with ``sym_rel`` already slab-relative; everything else matches
     :func:`walk_decode_pallas` minus the stream pointer (no ``q0``, no
-    ``qf`` output).
+    ``qf`` output).  ``tile_steps`` is the T tile, which
+    ``PallasExecutor.plan`` derives with :func:`check_vmem`.
 
     Returns int32 (n_steps, n_rows, 128), -1 where not kept.
     """
@@ -374,23 +410,26 @@ def walk_decode_symbol_pallas(slabs: jax.Array, sym_lut: jax.Array,
     R = rows_per_block
     assert L == LANES and n_rows % R == 0
     assert slabs.shape[0] == n_rows // R
+    assert n_steps % tile_steps == 0
     words = _walk_order_words(slabs, sym_rel, g_hi, ways=ways,
                               n_steps=n_steps, rows_per_block=R)
-    row_spec = pl.BlockSpec((R, L), lambda b: (b, 0))
-    step_spec = pl.BlockSpec((n_steps, R, L), lambda b: (0, b, 0))
+    row_spec = pl.BlockSpec((R, L), lambda b, j: (b, 0))
+    step_spec = pl.BlockSpec((tile_steps, R, L), lambda b, j: (j, b, 0))
     kernel = functools.partial(_walk_kernel_symbol, n_bits=n_bits, ways=ways,
-                               n_steps=n_steps, n_luts=len(luts))
+                               tile_steps=tile_steps, n_luts=len(luts))
     # The device op takes its HLO name from the innermost name-stack
     # component: ``name`` keeps it the kernel's, under the layer scope.
     with jax.named_scope(WALK_KERNEL):
         return pl.pallas_call(
             kernel,
-            grid=(n_rows // R,),
+            grid=(n_rows // R, n_steps // tile_steps),
             in_specs=[step_spec, *[_full_spec(a) for a in luts],
                       *[row_spec] * 8],
             out_specs=step_spec,
             out_shape=jax.ShapeDtypeStruct((n_steps, n_rows, L), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((R, L), jnp.int32)],
             compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
             name="walk_decode_symbol_pallas",

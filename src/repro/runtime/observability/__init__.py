@@ -83,6 +83,7 @@ SCHEMA = {
     "recoil_service_encode_fallbacks_total": ("counter", ()),
     "recoil_service_host_materializations_total": ("counter", ()),
     "recoil_service_plan_layout_total": ("counter", ("layout",)),
+    "recoil_serve_wire_bytes_total": ("counter", ()),
     # Engine / executor accounting
     "recoil_engine_executables": ("gauge", ()),
     "recoil_engine_stream_uploads_total": ("counter", ()),
@@ -91,6 +92,7 @@ SCHEMA = {
     "recoil_engine_policy_info": ("gauge", ("impl", "layout", "policy")),
     "recoil_engine_walk_slots_total": ("counter", ()),
     "recoil_engine_walk_symbols_total": ("counter", ()),
+    "recoil_engine_walk_tiles_total": ("counter", ()),
     # Per-plan-key profiler rollups
     "recoil_profiler_compiles_total": ("counter", ("session",)),
     "recoil_profiler_compile_seconds_total": ("counter", ("session",)),
@@ -233,13 +235,14 @@ def _service_samples(svc) -> list[dict]:
                   {"layout": "symbol"}))
     out.append(_c("recoil_service_plan_layout_total", st["pointer_plans"],
                   {"layout": "pointer"}))
+    out.append(_c("recoil_serve_wire_bytes_total", st["wire_bytes"]))
     return out
 
 
 def _engine_samples(svc) -> list[dict]:
     sess = svc.session
     ex = sess.executor
-    slots, symbols = sess.walk_totals()
+    slots, symbols, tiles = sess.walk_totals()
     return [
         _c("recoil_engine_executables", sess.executables),
         _c("recoil_engine_stream_uploads_total",
@@ -253,6 +256,7 @@ def _engine_samples(svc) -> list[dict]:
             "policy": getattr(ex.policy, "tag", "?")}),
         _c("recoil_engine_walk_slots_total", slots),
         _c("recoil_engine_walk_symbols_total", symbols),
+        _c("recoil_engine_walk_tiles_total", tiles),
     ]
 
 

@@ -1108,10 +1108,14 @@ class PipelineBroker:
     def snapshot(self) -> dict:
         """The pipeline's observable state: queue depths, wait/service
         latency percentiles, overlap ratio, counters (asserted in tests and
-        reported by ``bench_pipeline``).  ``walk_slots``/``walk_symbols``
-        are the decode session's running totals of walk positions
-        executed (padding included) and symbols answered."""
-        walk_slots, walk_symbols = self.svc.session.walk_totals()
+        reported by ``bench_pipeline``).  ``walk_slots``/``walk_symbols``/
+        ``walk_tiles`` are the decode session's running totals of walk
+        positions executed (padding included), symbols answered and walk
+        kernel grid steps; ``wire_bytes`` is the service's total of the
+        thinned containers of the requests it answered."""
+        walk_slots, walk_symbols, walk_tiles = \
+            self.svc.session.walk_totals()
+        wire_bytes = self.svc.stats.wire_bytes
         with self._cv:
             lanes = {lane: len(q) for lane, q in self._lanes.items() if q}
             depth = self._queued
@@ -1158,6 +1162,8 @@ class PipelineBroker:
             "stream_dispatches": self.stream_dispatches,
             "walk_slots": walk_slots,
             "walk_symbols": walk_symbols,
+            "walk_tiles": walk_tiles,
+            "wire_bytes": wire_bytes,
             "worker_restarts": reliability["worker_restarts"],
             "retries": reliability["retries"],
             "quarantine_rejects": reliability["quarantine_rejects"],

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import container
 from repro.core.encode import EncoderSession
 from repro.core.engine import (ChunkSpec, DecodePlan, DecoderSession,
                                DeviceStream, chunk_walk_batch,
@@ -147,6 +148,7 @@ class ServiceStats:
     host_materializations: int = 0  # lazy device->host stream copies (pallas)
     symbol_plans: int = 0      # requests planned on the symbol-indexed layout
     pointer_plans: int = 0     # requests planned on the pointer-walk fallback
+    wire_bytes: int = 0        # thinned containers of the answered requests
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -297,6 +299,12 @@ class DecodeService:
         # (name, n_threads) -> prepared request, two granularities: the
         # thinned WalkBatch (fusable) and the full DecodePlan (single path).
         self._batches: dict[tuple, tuple[WalkBatch, int]] = {}
+        # (name, n_threads) -> bytes of the container a client at that
+        # capability receives (``CapabilityRegistry.container_for_threads``),
+        # sized beside the thinned batch; answered requests add it to
+        # ``_wire_bytes``.
+        self._wire: dict[tuple, int] = {}
+        self._wire_bytes = 0
         self._plans: dict[tuple, DecodePlan] = {}
         # (name, n_threads, n_chunks) -> [(DecodePlan, ChunkSpec), ...]:
         # the chunk axis of the streaming path.  Each chunk's plan hits the
@@ -367,8 +375,8 @@ class DecodeService:
                 stream=stream, plan=plan,
                 final_states=np.asarray(final_states, np.uint32))
             self._generations[name] = self._generations.get(name, 0) + 1
-            for cache in (self._batches, self._plans,    # re-registration
-                          self._chunk_plans):
+            for cache in (self._batches, self._wire,     # re-registration
+                          self._plans, self._chunk_plans):
                 for key in [k for k in cache if k[0] == name]:
                     del cache[key]
             self._fused_plans.clear()
@@ -504,6 +512,8 @@ class DecodeService:
             plan = combine_plan(c.plan, n_threads)
             batch = WalkBatch.from_splits(
                 build_split_states(plan, c.final_states), plan.ways)
+            self._wire[key] = container.recoil_size(
+                self.session.model, plan, c.stream.n_words, c.final_states)
         self._batches[key] = (batch, plan.n_symbols)
         return self._batches[key]
 
@@ -541,12 +551,16 @@ class DecodeService:
         with self._lock:
             dropped = self._plans.pop(key, None) is not None
             dropped = (self._batches.pop(key, None) is not None) or dropped
+            self._wire.pop(key, None)
             return dropped
 
     def decode(self, name: str, n_threads: int) -> jax.Array:
         """Decode registered content at the client's parallelism; returns a
         device int32 symbol array (no host round-trip)."""
-        return self.session.execute(self.prepare_request(name, n_threads))
+        out = self.session.execute(self.prepare_request(name, n_threads))
+        with self._lock:
+            self._wire_bytes += self._wire.get((name, n_threads), 0)
+        return out
 
     # ------------------------------------------------------------------
     # Chunked streaming path (DESIGN.md §10)
@@ -848,6 +862,9 @@ class DecodeService:
         with self._lock:
             self._flushes += 1
             plan, sym_off = self._group_plan(reqs)
+            # A re-registration since the batches were built has dropped
+            # their sizes.
+            wire = sum(self._wire.get(key, 0) for _, key, _, _ in reqs)
         traces = [t.trace for t, _, _, _ in reqs]
         tp = time.perf_counter()
         for tr in traces:
@@ -880,6 +897,8 @@ class DecodeService:
                             or time.perf_counter()
                         ticket.trace.phase("delivery", td)
                         ticket.trace.finish("ok", td)
+        with self._lock:
+            self._wire_bytes += wire
 
     def _prepare_fused(self, reqs) -> tuple[DecodePlan, list[int], int]:
         streams: dict[int, DeviceStream] = {}
@@ -973,7 +992,8 @@ class DecodeService:
                 host_materializations=getattr(
                     self.session.executor, "host_materializations", 0),
                 symbol_plans=self.session.executor.layout_plans["symbol"],
-                pointer_plans=self.session.executor.layout_plans["pointer"])
+                pointer_plans=self.session.executor.layout_plans["pointer"],
+                wire_bytes=self._wire_bytes)
 
 
 def _validate_content(model: StaticModel, plan: RecoilPlan, stream,

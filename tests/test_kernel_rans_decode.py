@@ -173,9 +173,12 @@ def test_pallas_plan_refuses_pointer_content_on_tpu(monkeypatch):
         sess.prepare(batch, enc.stream, plan.n_symbols)
 
 
-def test_pallas_plan_refuses_walk_over_vmem_limit():
-    """A one-split walk of ~6.6k steps needs a whole-walk output block
-    over the kernel's VMEM limit: the plan names the limit."""
+def test_pallas_plan_tiles_walk_over_vmem_limit():
+    """A one-split walk of ~6.6k steps would need a whole-walk block over
+    the kernel's VMEM limit: the plan tiles the walk along its steps, each
+    tile's block fits, and the tiled walk decodes the content."""
+    from repro.kernels.rans_decode.rans_decode import (VMEM_LIMIT_BYTES,
+                                                       block_vmem_bytes)
     syms, model, enc = _make(n=210_000)
     plan = recoil.plan_splits(enc, 1)
     batch = WalkBatch.from_splits(
@@ -183,8 +186,61 @@ def test_pallas_plan_refuses_walk_over_vmem_limit():
     sess = DecoderSession(model, impl="pallas")
     ds = with_symbol_layout(sess.upload_stream(enc.stream), enc.k_of_word,
                             plan.n_symbols)
-    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
-        sess.prepare(batch, ds, plan.n_symbols)
+    dplan = sess.prepare(batch, ds, plan.n_symbols)
+    T, tile = dplan.statics["n_steps"], dplan.statics["tile_steps"]
+    rows = dplan.args[4].shape[0]
+    lut_words = dplan.args[1].size
+    assert block_vmem_bytes(T, 8, lut_words) > VMEM_LIMIT_BYTES
+    assert T // tile >= 2 and T % tile == 0
+    assert block_vmem_bytes(tile, 8, lut_words) <= VMEM_LIMIT_BYTES
+    assert tile in dplan.key
+    assert dplan.walk_tiles == T // tile * rows // 8
+    assert_allclose(np.asarray(sess.execute(dplan)), syms, rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("ways, contents, tile", [
+    (32, {"a": (40_000, 8)}, 64),                  # three tiles
+    (128, {"a": (40_000, 8)}, 16),                 # three tiles, W = 128
+    (32, {"a": (20_000, 2), "b": (3_000, 8)}, 64),  # b's splits < one tile
+    (32, {"a": (40_000, 8)}, 32),                  # last tile all padding
+], ids=["w32", "w128", "short_splits", "padding_tile"])
+def test_tiled_walk_matches_oracle_and_one_tile(ways, contents, tile):
+    """The walk tiled along its steps, the lane states carried from tile
+    to tile, equals the one-tile walk and the scalar oracle symbol for
+    symbol.  The tile is forced through the kernel's static argument."""
+    from repro.core.recoil import combine_plan
+    from repro.kernels.rans_decode import ops
+    from repro.runtime.serve import DecodeService
+    rng = np.random.default_rng(ways + tile)
+    payloads = {name: np.minimum(rng.exponential(40.0, size=n), 255)
+                .astype(np.int64) for name, (n, _) in contents.items()}
+    model = StaticModel.from_symbols(np.concatenate(list(payloads.values())),
+                                     256, RansParams(n_bits=11, ways=ways))
+    svc = DecodeService(model, impl="pallas")
+    svc.ingest_batch(payloads, 32)
+    group = [(name, cap) for name, (_, cap) in contents.items()]
+    plan = svc.prepare_group(group)
+    T = plan.statics["n_steps"]
+    assert plan.statics["tile_steps"] == T     # the whole walk fits VMEM
+    assert T // tile >= 3 and T % tile == 0
+    one = np.asarray(svc.session.execute(plan))
+    tiled = np.asarray(ops.decode_tiles_fused_symbol(
+        *plan.args, **{**plan.statics, "tile_steps": tile})
+        [:plan.n_symbols])
+    assert_allclose(tiled, one, rtol=0, atol=0)
+    oracle = []
+    for name, cap in group:
+        c = svc.content(name)
+        words = np.asarray(c.stream.words[:c.stream.n_words])
+        oracle.append(recoil.decode_recoil(combine_plan(c.plan, cap), words,
+                                           c.final_states, model))
+        assert_allclose(oracle[-1], payloads[name], rtol=0, atol=0)
+    assert_allclose(tiled, np.concatenate(oracle), rtol=0, atol=0)
+    steps = [-(-n // cap // ways) + 1 for n, cap in contents.values()]
+    if len(contents) > 1:
+        assert min(steps) < tile
+    if tile == 32:
+        assert max(steps) <= T - tile          # the last tile walks padding
 
 
 # ----------------------------------------------------------------------

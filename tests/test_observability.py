@@ -560,14 +560,17 @@ def test_walk_slot_counters_advance_exactly(impl):
     np.asarray(svc.session.execute(plan))
     np.asarray(svc.session.execute(plan))
     after = svc.session.walk_totals()
-    assert (after[0] - before[0], after[1] - before[1]) == (
-        2 * plan.walk_slots, 2 * plan.n_symbols)
+    assert tuple(a - b for a, b in zip(after, before)) == (
+        2 * plan.walk_slots, 2 * plan.n_symbols, 2 * plan.walk_tiles)
+    # Pallas walks one T tile per row block here; the XLA walk has no grid.
+    assert plan.walk_tiles == (k.shape[0] // 8 if impl == "pallas" else 0)
     snap = svc.metrics()
     assert snap["recoil_engine_walk_slots_total"]["values"][""] == after[0]
     assert snap["recoil_engine_walk_symbols_total"]["values"][""] == after[1]
+    assert snap["recoil_engine_walk_tiles_total"]["values"][""] == after[2]
     with svc.start_pipeline() as b:
         bs = b.snapshot()
-    assert (bs["walk_slots"], bs["walk_symbols"]) == after
+    assert (bs["walk_slots"], bs["walk_symbols"], bs["walk_tiles"]) == after
 
 
 def test_host_spans_reach_the_profiler_trace(tmp_path):

@@ -12,6 +12,7 @@ The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import base64
 import os
 import re
 
@@ -24,7 +25,8 @@ from repro.core.engine.plan import LEGACY_POLICY
 from repro.core.vectorized import _walk_batch_symbol_jit
 from repro.kernels.rans_decode import ops
 from repro.kernels.rans_decode.rans_decode import (LANES,
-                                                   POINTER_KERNEL_REFUSAL)
+                                                   POINTER_KERNEL_REFUSAL,
+                                                   check_vmem)
 from repro.runtime.observability import WALK_GATHER
 
 N_SYMBOLS = 10_000_000     # benchmarks.datasets.rand_exponential(50)
@@ -89,6 +91,29 @@ def _pallas_statics(sh: dict) -> dict:
                 n_symbols=sh["out"])
 
 
+def _symbol_statics(sh: dict) -> dict:
+    """The symbol-layout statics, with the T tile ``PallasExecutor.plan``
+    derives for the packed LUT's 2048 words."""
+    return dict(_pallas_statics(sh), tile_steps=check_vmem(
+        sh["steps"], ROWS_PER_BLOCK, 1 << N_BITS))
+
+
+def _kernel_grid(compiled) -> tuple[int, ...]:
+    """The iteration bounds of the walk kernel's Mosaic module in a
+    compiled executable: (row blocks, T tiles)."""
+    from jax._src.lib.mlir import ir
+    from jaxlib.mosaic.python import tpu
+    (call,) = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    body = base64.b64decode(re.search(r'"body":"([^"]+)"', call).group(1))
+    with ir.Context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        text = str(ir.Module.parse(body))
+    bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>", text)
+    return tuple(int(b) for b in bounds.group(1).split(","))
+
+
 @pytest.fixture(scope="module")
 def fused_symbol_decode(one_chip):
     """The fused symbol-layout decode compiled for the v5e, once per
@@ -99,20 +124,28 @@ def fused_symbol_decode(one_chip):
         if capability not in compiled:
             sh = _shapes(capability)
             compiled[capability] = ops.decode_tiles_fused_symbol.lower(
-                *_pallas_args(one_chip, sh), **_pallas_statics(sh)).compile()
+                *_pallas_args(one_chip, sh), **_symbol_statics(sh)).compile()
         return compiled[capability]
     return compile_for
 
 
-@pytest.mark.parametrize("capability", [2048, 256])
+@pytest.mark.parametrize("capability", [2048, 256, 16])
 def test_fused_symbol_decode_compiles_for_v5e(fused_symbol_decode,
                                               capability):
+    """Up to 256 splits the walk is one T tile; a 16-split client's walk
+    of 24,576 steps is over the VMEM limit as one block, so the compiled
+    kernel walks it in several tiles."""
     compiled = fused_symbol_decode(capability)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+    sh = _shapes(capability)
+    blocks, tiles = _kernel_grid(compiled)
+    assert blocks == sh["rows"] // ROWS_PER_BLOCK
+    assert tiles == sh["steps"] // _symbol_statics(sh)["tile_steps"]
+    assert (tiles > 1) == (capability == 16)
 
 
-@pytest.mark.parametrize("capability", [2048, 256])
+@pytest.mark.parametrize("capability", [2048, 256, 16])
 def test_walk_gather_reads_windows_not_elements(fused_symbol_decode,
                                                 capability):
     """The walk-order words are read as one window per split: no gather of
