@@ -282,8 +282,8 @@ class PallasExecutor(Executor):
 
     def plan(self, batch: WalkBatch, ds: DeviceStream,
              n_symbols: int) -> DecodePlan:
-        from repro.kernels.rans_decode.ops import (build_slabs, pack_batch,
-                                                   pad_to_rows)
+        from repro.kernels.rans_decode.ops import (build_slabs, ordered_runs,
+                                                   pack_batch, pad_to_rows)
         from repro.kernels.rans_decode.rans_decode import (
             LANES, POINTER_KERNEL_REFUSAL, check_vmem, window_guard)
         layout = self.select_layout(ds)
@@ -297,6 +297,11 @@ class PallasExecutor(Executor):
         packed, per_split, rows, pack, _ = pack_batch(batch)
         rows = pad_to_rows(packed, per_split, rows, pack,
                            self.policy.work(-(-rows // rpb)) * rpb)
+        # The scatter's ordered run copies; refuses a plan whose kept runs
+        # leave a gap or overlap, before any device work.
+        src, dst, n_runs = ordered_runs(per_split, n_steps=steps_b, ways=W,
+                                        n_symbols=n_symbols)
+        runs = (jnp.asarray(src), jnp.asarray(dst))
         out_b = self.policy.mem(n_symbols)
         statics = dict(n_bits=p.n_bits, ways=W, n_steps=steps_b,
                        rows_per_block=rpb, interpret=self.interpret,
@@ -333,14 +338,12 @@ class PallasExecutor(Executor):
                     jnp.asarray(packed["g_hi"]), jnp.asarray(packed["start"]),
                     jnp.asarray(packed["stop"]),
                     jnp.asarray(packed["keep_lo"]),
-                    jnp.asarray(packed["keep_hi"]),
-                    jnp.asarray(per_split["g_hi"]),
-                    jnp.asarray(per_split["out_base"]))
+                    jnp.asarray(packed["keep_hi"]), *runs)
             return DecodePlan(key=key, args=args, statics=statics,
                               n_symbols=n_symbols, out_bucket=out_b,
                               walk_slots=steps_b * rows * LANES,
                               walk_tiles=steps_b // tile * rows // rpb,
-                              layout=layout)
+                              scatter_runs=n_runs, layout=layout)
         host_words = self._host_words(ds)
         slabs, slab_lo = build_slabs(host_words, per_split, rows, pack, rpb)
         slab_b = self.policy.mem(slabs.shape[1], 8)
@@ -355,13 +358,12 @@ class PallasExecutor(Executor):
                 jnp.asarray(packed["x0"]), jnp.asarray(q0_rel),
                 jnp.asarray(packed["g_hi"]), jnp.asarray(packed["start"]),
                 jnp.asarray(packed["stop"]), jnp.asarray(packed["keep_lo"]),
-                jnp.asarray(packed["keep_hi"]),
-                jnp.asarray(per_split["g_hi"]),
-                jnp.asarray(per_split["out_base"]))
+                jnp.asarray(packed["keep_hi"]), *runs)
         return DecodePlan(key=key, args=args, statics=statics,
                           n_symbols=n_symbols, out_bucket=out_b,
                           walk_slots=steps_b * rows * LANES,
-                          walk_tiles=rows // rpb, layout=layout)
+                          walk_tiles=rows // rpb, scatter_runs=n_runs,
+                          layout=layout)
 
     def lower(self, plan: DecodePlan):
         from repro.kernels.rans_decode.ops import (decode_tiles_fused,

@@ -23,7 +23,10 @@ A :class:`DecodePlan` captures everything the executable call needs:
                    times every lane of the executor's tile, padding
                    included (``n_symbols`` of them answer symbols);
   * ``walk_tiles``— the walk kernel's grid steps: T tiles times row
-                   blocks (0 for the XLA walks, which have no grid).
+                   blocks (0 for the XLA walks, which have no grid);
+  * ``scatter_runs``— the non-empty kept runs the Pallas path's ordered
+                   run copy writes, one per split (0 for the XLA walks,
+                   which scatter by index).
 
 Bucketing policy (DESIGN.md §4): memory-dominant dims pad to powers of two
 (:func:`pow2_bucket`), compute-dominant dims to powers of two and their
@@ -231,6 +234,7 @@ class DecodePlan:
     out_bucket: int
     walk_slots: int
     walk_tiles: int = 0
+    scatter_runs: int = 0
     layout: str = "pointer"   # plan-IR layout axis (see LAYOUTS)
 
 

@@ -48,6 +48,7 @@ class EngineStats:
     walk_slots: int = 0    # walk positions executed, padding included
     walk_symbols: int = 0  # symbols those walks answered
     walk_tiles: int = 0    # walk-kernel grid steps: T tiles x row blocks
+    scatter_runs: int = 0  # kept runs the ordered run copy wrote
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -177,14 +178,15 @@ class DecoderSession:
             exes = list(self._exec.values())
         return [exe.as_text() for exe in exes]
 
-    def walk_totals(self) -> tuple[int, int, int]:
-        """``(walk_slots, walk_symbols, walk_tiles)``, read together: the
-        walk positions every executed plan ran (bucketed steps over all
-        its lanes, padding included), the symbols they answered, and the
-        walk kernel's grid steps (T tiles x row blocks)."""
+    def walk_totals(self) -> tuple[int, int, int, int]:
+        """``(walk_slots, walk_symbols, walk_tiles, scatter_runs)``, read
+        together: the walk positions every executed plan ran (bucketed
+        steps over all its lanes, padding included), the symbols they
+        answered, the walk kernel's grid steps (T tiles x row blocks), and
+        the kept runs the scatter copied."""
         with self._lock:
             return (self.stats.walk_slots, self.stats.walk_symbols,
-                    self.stats.walk_tiles)
+                    self.stats.walk_tiles, self.stats.scatter_runs)
 
     def execute(self, plan: DecodePlan) -> jax.Array:
         """Run a prepared plan: compile on bucket miss, else reuse.
@@ -197,6 +199,7 @@ class DecoderSession:
             self.stats.walk_slots += plan.walk_slots
             self.stats.walk_symbols += plan.n_symbols
             self.stats.walk_tiles += plan.walk_tiles
+            self.stats.scatter_runs += plan.scatter_runs
             exe = self._exec.get(plan.key)
             if exe is None:
                 if prof is None:

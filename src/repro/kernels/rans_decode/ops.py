@@ -8,10 +8,13 @@ Handles the host-side data plumbing around the kernel:
                        block's worst-case word consumption (kernel VMEM bound;
                        see rans_decode.py header), built with one vectorized
                        strided gather, with slab-relative ``q0``;
-  * scatter          — kernel emits (T, rows, 128) symbols (-1 = not kept);
-                       positions are reconstructed closed-form from
-                       ``g_hi - t`` and scattered into the flat output ON
-                       DEVICE (the tile never round-trips to host numpy).
+  * run copy         — kernel emits (T, rows, 128) symbols (-1 = not kept);
+                       each split's kept symbols are one contiguous run of
+                       the output, so the host orders the runs
+                       (:func:`ordered_runs`) and the device copies one
+                       window per split into the flat output, in order
+                       (:func:`scatter_outputs`; the tile never round-trips
+                       to host numpy).
 
 ``decode(...)`` is the user entry point; ``impl='jnp'`` routes to the pure
 jnp batched walk (same math, no Pallas) for CPU-fast paths and A/B tests.
@@ -75,7 +78,8 @@ def pack_batch(batch: WalkBatch):
         start=scalars(start), stop=scalars(stop), keep_lo=scalars(keep_lo),
         keep_hi=scalars(keep_hi))
     per_split = dict(q0=q0, g_hi=g_hi, out_base=out_base,
-                     span=start - stop + 1, start=start, sym_base=sym_base)
+                     span=start - stop + 1, start=start, sym_base=sym_base,
+                     keep_lo=keep_lo, keep_hi=keep_hi)
     return packed, per_split, rows, pack, S_pad
 
 
@@ -93,8 +97,7 @@ def pad_to_rows(packed: dict, per_split: dict, rows: int, pack: int,
                 fill = 2 ** 30
             packed[name] = np.concatenate(
                 [arr, np.full((pad_rows, LANES), fill, arr.dtype)], axis=0)
-        for name in ("q0", "g_hi", "out_base", "span", "start", "sym_base"):
-            a = per_split[name]
+        for name, a in per_split.items():
             per_split[name] = np.concatenate(
                 [a, np.zeros(pad_rows * pack, a.dtype)])
     return target_rows
@@ -157,9 +160,10 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
     fits it (8-bit symbols, n <= 12); the result is bit-identical either way.
     The kernel runs as the platform allows (:func:`interpret_mode`): this
     is the pointer-layout walk, so it runs on CPU only.
-    ``check`` asserts full output coverage (one device reduction + a host
-    sync; matches the jnp path's behavior — the engine's fused path skips
-    it to stay sync-free).
+    The splits' kept runs must tile ``[0, n_symbols)`` (:func:`ordered_runs`
+    raises otherwise); ``check`` also asserts that the kernel kept every
+    symbol of them (one device reduction + a host sync; matches the jnp
+    path's behavior — the engine's fused path skips it to stay sync-free).
     """
     if packed_lut is None:
         packed_lut = packed_lut_ok(model)
@@ -170,13 +174,11 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
                                  packed_lut=packed_lut)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}")
-    packed, per_split, rows, pack, S_pad = pack_batch(batch)
+    packed, per_split, rows, pack, _ = pack_batch(batch)
     rows = pad_to_rows(packed, per_split, rows, pack,
                        -(-rows // rows_per_block) * rows_per_block)
-    S_pad = rows * pack
     slabs, slab_lo = build_slabs(stream, per_split, rows, pack, rows_per_block)
     # q0 relative to the block slab
-    n_blocks = rows // rows_per_block
     lo_rows = np.repeat(slab_lo, rows_per_block).astype(np.int32)
     q0_rel = packed["q0"] - lo_rows[:, None]
     sym_lut, f_lut, F_lut = _luts(model, packed_lut)
@@ -189,8 +191,9 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
         jnp.asarray(packed["keep_hi"]),
         n_bits=model.params.n_bits, ways=batch.ways, n_steps=batch.n_steps,
         rows_per_block=rows_per_block, interpret=interpret_mode())
-    flat = scatter_outputs(out, jnp.asarray(per_split["g_hi"]),
-                           jnp.asarray(per_split["out_base"]),
+    src, dst, _ = ordered_runs(per_split, n_steps=batch.n_steps,
+                               ways=batch.ways, n_symbols=n_symbols)
+    flat = scatter_outputs(out, jnp.asarray(src), jnp.asarray(dst),
                            ways=batch.ways, pack=pack, n_symbols=n_symbols)
     if check:
         assert bool(jnp.all(flat >= 0)), \
@@ -198,39 +201,93 @@ def decode(batch: WalkBatch, stream: np.ndarray, model: StaticModel,
     return flat
 
 
-@functools.partial(jax.jit, static_argnames=("ways", "pack", "n_symbols"))
-def scatter_outputs(out_tiles: jax.Array, g_hi: jax.Array, out_base: jax.Array,
-                    *, ways: int, pack: int, n_symbols: int) -> jax.Array:
-    """(T, rows, 128) kernel tiles -> flat decoded symbols, on device.
+def ordered_runs(per_split: dict, *, n_steps: int, ways: int,
+                 n_symbols: int) -> tuple[np.ndarray, np.ndarray, int]:
+    """The copies of :func:`scatter_outputs`, in output order.
 
-    The closed-form position reconstruction of ``_walk_batch_jit``: kept
-    positions are unique by construction, non-kept lanes scatter out of
-    bounds and are removed by ``mode="drop"`` — the (T, rows, 128) tile is
-    never materialized on host.
+    A split keeps walk indices ``keep_lo <= i < keep_hi`` at output
+    position ``out_base + i``, so its kept symbols are one contiguous run.
+    Its ``(T, W)`` tile, steps reversed and flattened, lists positions
+    ``out_base + (g_hi - T + 1) * W + o``, so the run starts at ``o = a =
+    keep_lo - (g_hi - T + 1) * W`` of the split's ``T * W`` words: it is
+    the source ``src = s * T * W + a``, written at ``dst = out_base +
+    keep_lo``.
+
+    Returns ``(src, dst, n_runs)``: one copy per split, ordered by ``dst``
+    and led by repeats of the first run in place of the empty runs (inert
+    padding), plus a last copy of the source's ``-1`` guard to ``dst =
+    n_symbols``; ``n_runs`` counts the non-empty runs.  Raises unless the
+    runs tile ``[0, n_symbols)`` exactly and each lies in its tile.
+    """
+    T, W = n_steps, ways
+    TW = T * W
+    S = len(per_split["keep_lo"])
+    if (S + 1) * TW >= 2 ** 31:
+        raise ValueError(f"{S} splits x {TW} walk slots exceed int32 "
+                         "copy offsets")
+    keep_lo = per_split["keep_lo"].astype(np.int64)
+    length = per_split["keep_hi"].astype(np.int64) - keep_lo
+    a = keep_lo - (per_split["g_hi"].astype(np.int64) - T + 1) * W
+    src = np.arange(S, dtype=np.int64) * TW + a
+    dst = per_split["out_base"].astype(np.int64) + keep_lo
+    kept = length > 0
+    if np.any(length < 0) or np.any(a[kept] < 0) \
+            or np.any(a[kept] + length[kept] > TW):
+        raise ValueError("a split's kept run lies outside its walk")
+    order = np.argsort(dst[kept], kind="stable")
+    src, dst, length = src[kept][order], dst[kept][order], length[kept][order]
+    ends = dst + length
+    if not np.array_equal(dst, np.concatenate([[0], ends[:-1]])) \
+            or (ends[-1] if len(ends) else 0) != n_symbols:
+        raise ValueError(
+            f"the splits' kept runs do not tile [0, {n_symbols}) exactly")
+    lead = S - len(src)
+    src = np.concatenate([np.full(lead, src[0] if len(src) else 0), src,
+                          [S * TW]])
+    dst = np.concatenate([np.full(lead, 0), dst, [n_symbols]])
+    return src.astype(np.int32), dst.astype(np.int32), len(ends)
+
+
+@functools.partial(jax.jit, static_argnames=("ways", "pack", "n_symbols"))
+def scatter_outputs(out_tiles: jax.Array, src: jax.Array, dst: jax.Array,
+                    *, ways: int, pack: int, n_symbols: int) -> jax.Array:
+    """(T, rows, 128) kernel tiles -> flat decoded symbols, on device, as
+    the ordered run copies of :func:`ordered_runs` (DESIGN.md §4).
+
+    The tiles are relaid to one ``T * W``-word block per split, steps
+    reversed, so each split's kept run is one contiguous slice.  Copy ``c``
+    writes the ``T * W`` words from ``src[c]`` at ``dst[c]``: its run, then
+    a tail that is not its own, which the next copy (starting where the
+    run ended) overwrites.  The last copy writes the source's ``-1`` guard
+    over the tail beyond the symbols.  Both the source and the output carry
+    ``T * W`` guard words, so no slice start is ever clamped (a clamped
+    start would shift a whole run).
     """
     with jax.named_scope(SCATTER):
-        T, rows, L = out_tiles.shape
-        S_pad = rows * pack
-        # (T, rows, pack, W) -> (S_pad, T, W)
+        T, rows, _ = out_tiles.shape
+        TW = T * ways
+        # (T, rows, pack, W) -> (rows, pack, T, W), steps reversed
         tiles = out_tiles.reshape(T, rows, pack, ways).transpose(1, 2, 0, 3)
-        tiles = tiles.reshape(S_pad, T, ways)
-        t = jnp.arange(T, dtype=jnp.int32)
-        lane = jnp.arange(ways, dtype=jnp.int32)
-        i = ((g_hi[:, None, None].astype(jnp.int32) - t[None, :, None])
-             * ways + lane[None, None, :]
-             + out_base[:, None, None].astype(jnp.int32))
-        i = jnp.where(tiles >= 0, i, n_symbols)
-        outv = jnp.full((n_symbols,), -1, dtype=jnp.int32)
-        return outv.at[i.reshape(-1)].set(tiles.reshape(-1), mode="drop",
-                                          unique_indices=True)
+        guard = jnp.full((TW,), -1, jnp.int32)
+        flat = jnp.concatenate([jax.lax.rev(tiles, (2,)).reshape(-1), guard])
+
+        def copy(c, outv):
+            window = jax.lax.dynamic_slice(flat, (src[c],), (TW,))
+            return jax.lax.dynamic_update_slice(outv, window, (dst[c],))
+
+        outv = jnp.full((n_symbols + TW,), -1, jnp.int32)
+        # Eight copies an iteration: on a v5e the loop's own overhead is
+        # about a fifth of a 6,144-word copy's time.
+        outv = jax.lax.fori_loop(0, src.shape[0], copy, outv, unroll=8)
+        return outv[:n_symbols]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "n_bits", "ways", "n_steps", "rows_per_block", "interpret", "pack",
     "n_symbols"))
 def decode_tiles_fused(slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi,
-                       start, stop, keep_lo, keep_hi, g_hi_split,
-                       out_base_split, *, n_bits: int, ways: int,
+                       start, stop, keep_lo, keep_hi, run_src, run_dst, *,
+                       n_bits: int, ways: int,
                        n_steps: int, rows_per_block: int, interpret: bool,
                        pack: int, n_symbols: int) -> jax.Array:
     """Pallas walk + on-device scatter as ONE executable — the unit the
@@ -240,16 +297,16 @@ def decode_tiles_fused(slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi,
         slabs, sym_lut, f_lut, F_lut, k, y, x0, q0, g_hi, start, stop,
         keep_lo, keep_hi, n_bits=n_bits, ways=ways, n_steps=n_steps,
         rows_per_block=rows_per_block, interpret=interpret)
-    return scatter_outputs(out, g_hi_split, out_base_split, ways=ways,
-                           pack=pack, n_symbols=n_symbols)
+    return scatter_outputs(out, run_src, run_dst, ways=ways, pack=pack,
+                           n_symbols=n_symbols)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "n_bits", "ways", "n_steps", "rows_per_block", "interpret", "pack",
     "n_symbols", "tile_steps"))
 def decode_tiles_fused_symbol(slabs, sym_lut, f_lut, F_lut, k, y, x0, sym_rel,
-                              g_hi, start, stop, keep_lo, keep_hi, g_hi_split,
-                              out_base_split, *, n_bits: int, ways: int,
+                              g_hi, start, stop, keep_lo, keep_hi, run_src,
+                              run_dst, *, n_bits: int, ways: int,
                               n_steps: int, rows_per_block: int,
                               interpret: bool, pack: int, n_symbols: int,
                               tile_steps: int) -> jax.Array:
@@ -264,8 +321,8 @@ def decode_tiles_fused_symbol(slabs, sym_lut, f_lut, F_lut, k, y, x0, sym_rel,
         keep_lo, keep_hi, n_bits=n_bits, ways=ways, n_steps=n_steps,
         rows_per_block=rows_per_block, interpret=interpret,
         tile_steps=tile_steps)
-    return scatter_outputs(out, g_hi_split, out_base_split, ways=ways,
-                           pack=pack, n_symbols=n_symbols)
+    return scatter_outputs(out, run_src, run_dst, ways=ways, pack=pack,
+                           n_symbols=n_symbols)
 
 
 def decode_recoil_kernel(plan, stream, final_states, model: StaticModel,

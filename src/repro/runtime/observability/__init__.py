@@ -47,7 +47,7 @@ __all__ = [
 # Device scopes of the fused decode executable's three stages: the
 # walk-order gather of the stream words
 # (``rans_decode._walk_order_words``), the Pallas walk kernel's
-# ``pallas_call``, and the scatter of its tiles into the flat output
+# ``pallas_call``, and the copy of its tiles' kept runs into the flat output
 # (``ops.scatter_outputs``).  A scope changes HLO metadata only, never the
 # compiled code.
 WALK_GATHER, WALK_KERNEL, SCATTER = SCOPES = (
@@ -93,6 +93,7 @@ SCHEMA = {
     "recoil_engine_walk_slots_total": ("counter", ()),
     "recoil_engine_walk_symbols_total": ("counter", ()),
     "recoil_engine_walk_tiles_total": ("counter", ()),
+    "recoil_engine_scatter_runs_total": ("counter", ()),
     # Per-plan-key profiler rollups
     "recoil_profiler_compiles_total": ("counter", ("session",)),
     "recoil_profiler_compile_seconds_total": ("counter", ("session",)),
@@ -242,7 +243,7 @@ def _service_samples(svc) -> list[dict]:
 def _engine_samples(svc) -> list[dict]:
     sess = svc.session
     ex = sess.executor
-    slots, symbols, tiles = sess.walk_totals()
+    slots, symbols, tiles, runs = sess.walk_totals()
     return [
         _c("recoil_engine_executables", sess.executables),
         _c("recoil_engine_stream_uploads_total",
@@ -257,6 +258,7 @@ def _engine_samples(svc) -> list[dict]:
         _c("recoil_engine_walk_slots_total", slots),
         _c("recoil_engine_walk_symbols_total", symbols),
         _c("recoil_engine_walk_tiles_total", tiles),
+        _c("recoil_engine_scatter_runs_total", runs),
     ]
 
 

@@ -1109,11 +1109,12 @@ class PipelineBroker:
         """The pipeline's observable state: queue depths, wait/service
         latency percentiles, overlap ratio, counters (asserted in tests and
         reported by ``bench_pipeline``).  ``walk_slots``/``walk_symbols``/
-        ``walk_tiles`` are the decode session's running totals of walk
-        positions executed (padding included), symbols answered and walk
-        kernel grid steps; ``wire_bytes`` is the service's total of the
+        ``walk_tiles``/``scatter_runs`` are the decode session's running
+        totals of walk positions executed (padding included), symbols
+        answered, walk kernel grid steps and kept runs copied;
+        ``wire_bytes`` is the service's total of the
         thinned containers of the requests it answered."""
-        walk_slots, walk_symbols, walk_tiles = \
+        walk_slots, walk_symbols, walk_tiles, scatter_runs = \
             self.svc.session.walk_totals()
         wire_bytes = self.svc.stats.wire_bytes
         with self._cv:
@@ -1163,6 +1164,7 @@ class PipelineBroker:
             "walk_slots": walk_slots,
             "walk_symbols": walk_symbols,
             "walk_tiles": walk_tiles,
+            "scatter_runs": scatter_runs,
             "wire_bytes": wire_bytes,
             "worker_restarts": reliability["worker_restarts"],
             "retries": reliability["retries"],

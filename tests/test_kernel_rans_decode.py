@@ -18,7 +18,7 @@ from repro.core import conventional, recoil
 from repro.core.recoil import build_split_states
 from repro.core.vectorized import WalkBatch, encode_interleaved_fast
 from repro.kernels.rans_decode import decode, decode_recoil_kernel
-from repro.kernels.rans_decode.rans_decode import interpret_mode
+from repro.kernels.rans_decode.rans_decode import LANES, interpret_mode
 from repro.kernels.rans_decode.ref import decode_reference, walk_reference
 
 
@@ -326,3 +326,144 @@ def test_walk_order_windows_match_elementwise_gather(ways, layout):
         assert not in_range.all()     # some windows start below the slab
     if layout == "deep":
         assert (rel_lanes(lo) % ways).any()
+
+
+# ----------------------------------------------------------------------
+# Output: one ordered contiguous copy per split
+# ----------------------------------------------------------------------
+
+def _closed_form_scatter(tiles, per_split, *, ways, pack, n_out):
+    """The reference: every kept slot written at its closed-form position
+    ``out_base + (g_hi - t) * W + lane``; ``-1`` wherever none is kept."""
+    T, rows, L = tiles.shape
+    s = np.arange(rows)[:, None] * pack + np.arange(L)[None, :] // ways
+    t = np.arange(T)[:, None, None]
+    i = ((per_split["g_hi"][s][None] - t) * ways
+         + (np.arange(L) % ways)[None, None, :] + per_split["out_base"][s][None])
+    out = np.full(n_out, -1, np.int32)
+    kept = tiles >= 0
+    assert len(np.unique(i[kept])) == kept.sum()
+    out[i[kept]] = tiles[kept]
+    return out
+
+
+def _asset_splits(rng, lengths, n_steps, ways):
+    """Completion-ordered splits of one asset with runs of ``lengths``, each
+    with a top group ``g_hi`` drawn anywhere its T-step walk covers its run
+    (so the run's offset in its tile varies)."""
+    hi = np.cumsum(lengths)
+    lo = hi - lengths
+    g_min = -(-hi // ways) - 1
+    g_max = lo // ways + n_steps - 1
+    assert np.all(g_min <= g_max)
+    g_hi = rng.integers(g_min, g_max + 1)
+    return g_hi, lo, hi
+
+
+@pytest.mark.parametrize("case", [
+    "short_runs", "long_walk", "inert_padding", "out_of_order",
+    "eight_assets", "bucket_edge", "short_asset"])
+def test_scatter_run_copy_matches_closed_form_scatter(case):
+    """The ordered run copy gives the closed-form index scatter's output
+    bit for bit, ``-1`` beyond the symbols included.
+
+    ``short_runs``: 64 splits of one-group runs and up (c2048-like);
+    ``long_walk``: 3 splits walking 240 steps (c16-like);
+    ``inert_padding``: rows of inert splits (``start = -1``, empty runs);
+    ``out_of_order``: the splits' rows in no output order;
+    ``eight_assets``: 8 assets placed one after another by ``out_base``;
+    ``bucket_edge``: the last run ends at the bucket's edge, so the last
+    copies reach into the output's guard;
+    ``short_asset``: fewer symbols than one split's ``T * W`` words."""
+    from repro.kernels.rans_decode.ops import ordered_runs, scatter_outputs
+    rng = np.random.default_rng(sum(map(ord, case)))
+    ways, T = (32, 240) if case == "long_walk" else (32, 12)
+    if case == "short_asset":
+        ways = 16
+    pack = LANES // ways
+    n_assets = {"eight_assets": 8, "out_of_order": 3}.get(case, 1)
+    n_splits = {"long_walk": 3, "short_asset": 4,
+                "inert_padding": 61}.get(case, 64)
+    cols = {k: [] for k in ("g_hi", "out_base", "keep_lo", "keep_hi")}
+    base = 0
+    for _ in range(n_assets):
+        if case == "long_walk":
+            lengths = rng.integers((T - 20) * ways, (T - 2) * ways, n_splits)
+        elif case == "short_asset":
+            lengths = rng.integers(1, 2 * ways, n_splits)
+        else:
+            lengths = rng.integers(1, (T - 2) * ways, n_splits)
+            lengths[rng.random(n_splits) < 0.25] = 1
+        g_hi, lo, hi = _asset_splits(rng, lengths, T, ways)
+        for k, v in (("g_hi", g_hi), ("keep_lo", lo), ("keep_hi", hi),
+                     ("out_base", np.full(n_splits, base))):
+            cols[k].append(v)
+        base += int(hi[-1])
+    n_symbols = base
+    cols = {k: np.concatenate(v).astype(np.int32) for k, v in cols.items()}
+    S = len(cols["g_hi"])
+    order = (rng.permutation(S) if case in ("out_of_order", "eight_assets")
+             else np.arange(S))
+    n_inert = -S % pack + (2 * pack if case == "inert_padding" else 0)
+    per_split = {k: np.concatenate([v[order], np.zeros(n_inert, np.int32)])
+                 for k, v in cols.items()}
+    S_pad = S + n_inert
+    rows = S_pad // pack
+    assert S_pad % pack == 0
+    n_out = n_symbols if case == "bucket_edge" else n_symbols + 3 * T * ways
+    if case == "short_asset":
+        assert n_symbols < T * ways
+
+    # Tiles as the kernel leaves them: symbols at kept slots, -1 elsewhere.
+    s = np.arange(rows)[:, None] * pack + np.arange(LANES)[None, :] // ways
+    i = ((per_split["g_hi"][s][None] - np.arange(T)[:, None, None]) * ways
+         + (np.arange(LANES) % ways)[None, None, :])
+    kept = (i >= per_split["keep_lo"][s][None]) & \
+        (i < per_split["keep_hi"][s][None])
+    tiles = np.where(kept, rng.integers(0, 2**31 - 1, kept.shape),
+                     -1).astype(np.int32)
+    assert kept.sum() == n_symbols
+
+    src, dst, n_runs = ordered_runs(per_split, n_steps=T, ways=ways,
+                                    n_symbols=n_symbols)
+    assert n_runs == S and len(src) == len(dst) == S_pad + 1
+    assert np.all(np.diff(dst) >= 0) and dst[-1] == n_symbols
+    if case == "bucket_edge":
+        assert dst[-2] + T * ways > n_out     # the copy needs the guard
+    ref = _closed_form_scatter(tiles, per_split, ways=ways, pack=pack,
+                               n_out=n_out)
+    got = np.asarray(scatter_outputs(
+        jnp.asarray(tiles), jnp.asarray(src), jnp.asarray(dst), ways=ways,
+        pack=pack, n_symbols=n_out))
+    assert got.dtype == np.int32 and got.shape == (n_out,)
+    assert np.array_equal(got, ref)
+    assert (got[:n_symbols] >= 0).all() and (got[n_symbols:] == -1).all()
+
+
+@pytest.mark.parametrize("fault", ["gap", "overlap", "short"])
+def test_kept_runs_that_do_not_tile_are_refused(fault):
+    """A plan whose kept runs leave a gap, overlap, or stop short of the
+    symbols is refused on the host, before any device work, by the engine's
+    Pallas plan and by ``ops.decode(check=True)``; the plan as built
+    decodes."""
+    syms, model, enc = _make(n=6_000)
+    plan = recoil.plan_splits(enc, 6)
+    batch = WalkBatch.from_splits(
+        build_split_states(plan, enc.final_states), plan.ways)
+    sess = DecoderSession(model, impl="pallas")
+    ds = with_symbol_layout(sess.upload_stream(enc.stream), enc.k_of_word,
+                            plan.n_symbols)
+    good = sess.prepare(batch, ds, plan.n_symbols)
+    assert good.scatter_runs == len(batch.keep_lo)
+    assert_allclose(np.asarray(sess.execute(good)), syms, rtol=0, atol=0)
+    n = plan.n_symbols
+    if fault == "gap":
+        batch.keep_hi[2] -= 1
+    elif fault == "overlap":
+        batch.keep_lo[3] -= 1
+    else:
+        n += 1
+    with pytest.raises(ValueError, match="do not tile"):
+        sess.prepare(batch, ds, n)
+    with pytest.raises(ValueError, match="do not tile"):
+        decode(batch, enc.stream, model, n, check=True)

@@ -561,7 +561,8 @@ def test_walk_slot_counters_advance_exactly(impl):
     np.asarray(svc.session.execute(plan))
     after = svc.session.walk_totals()
     assert tuple(a - b for a, b in zip(after, before)) == (
-        2 * plan.walk_slots, 2 * plan.n_symbols, 2 * plan.walk_tiles)
+        2 * plan.walk_slots, 2 * plan.n_symbols, 2 * plan.walk_tiles,
+        2 * plan.scatter_runs)
     # Pallas walks one T tile per row block here; the XLA walk has no grid.
     assert plan.walk_tiles == (k.shape[0] // 8 if impl == "pallas" else 0)
     snap = svc.metrics()
@@ -570,7 +571,35 @@ def test_walk_slot_counters_advance_exactly(impl):
     assert snap["recoil_engine_walk_tiles_total"]["values"][""] == after[2]
     with svc.start_pipeline() as b:
         bs = b.snapshot()
-    assert (bs["walk_slots"], bs["walk_symbols"], bs["walk_tiles"]) == after
+    assert (bs["walk_slots"], bs["walk_symbols"], bs["walk_tiles"],
+            bs["scatter_runs"]) == after
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_scatter_run_counter_counts_kept_runs(impl):
+    """``recoil_engine_scatter_runs_total`` advances by the non-empty kept
+    runs of every executed plan: one a split on the Pallas path, whose
+    scatter copies them in order, none on the XLA walk."""
+    payloads = _payloads(n_contents=2)
+    svc = _service(payloads, impl=impl)
+    plans = [svc.prepare_request("c0", 8), svc.prepare_request("c1", 16),
+             svc.prepare_group([("c0", 16), ("c1", 4)])]
+    runs = [int((batch.keep_hi > batch.keep_lo).sum())
+            for batch in (svc._thinned_batch("c0", 8)[0],
+                          svc._thinned_batch("c1", 16)[0])]
+    runs.append(sum(int((b.keep_hi > b.keep_lo).sum()) for b in (
+        svc._thinned_batch("c0", 16)[0], svc._thinned_batch("c1", 4)[0])))
+    assert runs == [8, 16, 20]
+    assert [p.scatter_runs for p in plans] == (
+        runs if impl == "pallas" else [0, 0, 0])
+    total = 0
+    for plan in plans:
+        before = svc.session.walk_totals()[3]
+        np.asarray(svc.session.execute(plan))
+        assert svc.session.walk_totals()[3] - before == plan.scatter_runs
+        total += plan.scatter_runs
+    snap = svc.metrics()
+    assert snap["recoil_engine_scatter_runs_total"]["values"][""] == total
 
 
 def test_host_spans_reach_the_profiler_trace(tmp_path):

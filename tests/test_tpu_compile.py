@@ -27,7 +27,7 @@ from repro.kernels.rans_decode import ops
 from repro.kernels.rans_decode.rans_decode import (LANES,
                                                    POINTER_KERNEL_REFUSAL,
                                                    check_vmem)
-from repro.runtime.observability import WALK_GATHER
+from repro.runtime.observability import SCATTER, WALK_GATHER
 
 N_SYMBOLS = 10_000_000     # benchmarks.datasets.rand_exponential(50)
 WAYS = 32
@@ -77,12 +77,12 @@ def _sds(sharding, shape, dtype=jnp.int32):
 
 def _pallas_args(sharding, sh: dict):
     rows = _sds(sharding, (sh["rows"], LANES))
-    split = _sds(sharding, (sh["rows"] * PACK,))
+    copies = _sds(sharding, (sh["rows"] * PACK + 1,))
     slabs = _sds(sharding, (sh["rows"] // ROWS_PER_BLOCK, sh["slab"]))
     lut = _sds(sharding, (1 << N_BITS,))
-    # slabs, packed LUT (f/F tables None), 9 lane-packed tiles,
-    # per-split g_hi and out_base
-    return (slabs, lut, None, None, *[rows] * 9, split, split)
+    # slabs, packed LUT (f/F tables None), 9 lane-packed tiles, the
+    # scatter's run sources and destinations (one a split, one more)
+    return (slabs, lut, None, None, *[rows] * 9, copies, copies)
 
 
 def _pallas_statics(sh: dict) -> dict:
@@ -157,6 +157,19 @@ def test_walk_gather_reads_windows_not_elements(fused_symbol_decode,
                    if set(re.search(r"slice_sizes=\{([\d,]*)\}", g)
                           .group(1).split(",")) == {"1"}]
     assert not elementwise, elementwise[0]
+
+
+@pytest.mark.parametrize("capability", [2048, 256, 16])
+def test_scatter_copies_runs_without_sort(fused_symbol_decode, capability):
+    """The output is written as one ordered copy per split: the compiled
+    executable sorts nothing, and no scatter of the ``recoil.scatter``
+    scope is left."""
+    hlo = fused_symbol_decode(capability).as_text().splitlines()
+    sorts = [line for line in hlo if re.search(r"\ssort\(", line)]
+    scatters = [line for line in hlo
+                if re.search(r"\sscatter\(", line) and SCATTER in line]
+    assert not sorts, sorts[0]
+    assert not scatters, scatters[0]
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
